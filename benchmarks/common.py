@@ -34,9 +34,11 @@ def save_json(name: str, payload):
 
 
 def run_with_devices(n_devices: int, code: str) -> dict:
-    """Run a python snippet in a subprocess with n fake XLA devices; the
+    """Run a python snippet in a subprocess with n fake XLA CPU devices
+    (pinned to the CPU, so it never contends for an accelerator); the
     snippet must print one JSON line to stdout."""
     env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": f"--xla_force_host_platform_device_count={n_devices}",
            "PYTHONPATH": "src"}
     proc = subprocess.run([sys.executable, "-c", code], env=env,

@@ -15,8 +15,11 @@ DELETE, and exit 0 on SIGTERM.
 
   PYTHONPATH=src python scripts/serve_smoke.py --chaos
 
-(``--chaos-daemon ROOT PORTFILE PHASE`` is the internal subprocess
-entry point.)
+(``--chaos-daemon ROOT PORTFILE PHASE`` and ``--chaos-reference`` are
+the internal subprocess entry points.  The orchestrating parent never
+touches JAX itself: on an accelerator only one process at a time may hold
+the device, so the reference values come from a child that exits before
+the first daemon starts.)
 """
 import json
 import os
@@ -135,17 +138,34 @@ def chaos_daemon(argv) -> int:
     return 0
 
 
+def _chaos_data() -> dict:
+    return {f"c{i}": bsbm_ntriples(120, seed=i) for i in (1, 2, 3)}
+
+
+def chaos_reference() -> int:
+    """Internal: print the direct ``qa.assess`` values of every chaos
+    dataset as one JSON line."""
+    from repro import qa
+    print(json.dumps({name: {k: float(v) for k, v in sorted(
+        qa.assess(text, metrics="paper", base=BASE).values.items())}
+        for name, text in _chaos_data().items()}))
+    return 0
+
+
 def chaos() -> None:
     """Orchestrate the crash/replay cycle and gate on zero lost jobs."""
     import shutil
     import signal
     import subprocess
 
-    from repro import qa
-
+    env = {**os.environ, "PYTHONPATH": SRC}
+    refs = json.loads(subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--chaos-reference"],
+        env=env, capture_output=True, text=True, check=True,
+        timeout=300).stdout.splitlines()[-1])
     root = tempfile.mkdtemp(prefix="qa-serve-chaos-")
     portfile = os.path.join(root, ".port")
-    data = {f"c{i}": bsbm_ntriples(120, seed=i) for i in (1, 2, 3)}
+    data = _chaos_data()
     procs = []
 
     def spawn(phase):
@@ -153,8 +173,7 @@ def chaos() -> None:
             os.remove(portfile)
         p = subprocess.Popen(
             [sys.executable, os.path.abspath(__file__),
-             "--chaos-daemon", root, portfile, phase],
-            env={**os.environ, "PYTHONPATH": SRC})
+             "--chaos-daemon", root, portfile, phase], env=env)
         procs.append(p)
         deadline = time.time() + 180
         while not os.path.exists(portfile):
@@ -191,9 +210,7 @@ def chaos() -> None:
             if j["state"] != "done":
                 lost.append((name, j["error"]))
                 continue
-            ref = qa.assess(data[name], metrics="paper", base=BASE)
-            assert j["values"] == {k: float(v) for k, v in
-                                   sorted(ref.values.items())}, name
+            assert j["values"] == refs[name], name
         assert not lost, f"jobs lost across the crash: {lost}"
         # c2's replay was also made transiently flaky: retried once
         st, j2 = _req(api, "GET", f"/datasets/c2/jobs/{job_ids['c2']}")
@@ -240,6 +257,8 @@ if __name__ == "__main__":
     if "--chaos-daemon" in sys.argv:
         i = sys.argv.index("--chaos-daemon")
         sys.exit(chaos_daemon(sys.argv[i + 1:i + 4]))
+    elif "--chaos-reference" in sys.argv:
+        sys.exit(chaos_reference())
     elif "--chaos" in sys.argv:
         sys.exit(chaos())
     else:

@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..data.sampler import subgraph_shape
-from .. import compat
 from ..dist.sharding import ShardingPolicy
 from ..optim import AdamW
 from .base import Bundle, pad_to
@@ -156,7 +155,7 @@ def gnn_partitioned_bundle(mesh, shape_info, *, params_abs, local_loss,
             for ax in axes:
                 loss = jax.lax.pmean(loss, ax)
             return loss
-        return compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), {k: P(axes) for k in batch_sds}),
             out_specs=P(), check_vma=False)(params, batch)

@@ -43,7 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import compat
 from ..kernels import count_scans, record_scan
 from ..rdf.triple_tensor import TripleTensor, COL_S_FLAGS, N_PLANES
 from . import sketches as hll
@@ -87,8 +86,7 @@ def _counts_masks(planes, exprs):
 class QualityEvaluator:
     def __init__(self, metric_names: Sequence[str] = ALL_METRICS, *,
                  fused: bool = True, backend: str = "jnp",
-                 mesh: Mesh | None = None, hll_p: int = hll.DEFAULT_P,
-                 interpret: bool = True):
+                 mesh: Mesh | None = None, hll_p: int = hll.DEFAULT_P):
         if backend not in BACKENDS:
             raise ValueError(
                 f"backend must be one of {BACKENDS}, got {backend!r}")
@@ -97,7 +95,6 @@ class QualityEvaluator:
         self.backend = backend
         self.mesh = mesh
         self.hll_p = hll_p
-        self.interpret = interpret  # pallas interpret mode (CPU container)
         self.plans: list[Plan] = (
             [plan(self.metrics)] if fused
             else [plan_single(m) for m in self.metrics])
@@ -113,19 +110,17 @@ class QualityEvaluator:
         """
         program, n_counters = pln.program, pln.n_counters
         sketch_specs = pln.sketch_specs
-        backend, interpret, hll_p = self.backend, self.interpret, self.hll_p
+        backend, hll_p = self.backend, self.hll_p
 
         def local_pass(planes):
             if backend == "fused_scan":
                 from ..kernels.fused_scan import ops as fops
                 counts, regs = fops.fused_scan(
-                    planes, program, n_counters, sketch_specs, hll_p,
-                    interpret=interpret)
+                    planes, program, n_counters, sketch_specs, hll_p)
                 return counts, regs
             if backend == "pallas":
                 from ..kernels.qap_count import ops as qops
-                counts = qops.fused_count(planes, program, n_counters,
-                                          interpret=interpret)
+                counts = qops.fused_count(planes, program, n_counters)
             else:
                 record_scan(1)  # the counts scan
                 counts = _counts_jnp(planes, program, n_counters)
@@ -135,8 +130,7 @@ class QualityEvaluator:
                 for sname, cols in sketch_specs:
                     if backend == "pallas":
                         from ..kernels.hll import ops as hops
-                        regs[sname] = hops.hll_fold(planes, cols, hll_p,
-                                                    interpret=interpret)
+                        regs[sname] = hops.hll_fold(planes, cols, hll_p)
                     else:
                         record_scan(1)  # one more scan per sketch
                         regs[sname] = hll.hll_update(
@@ -162,7 +156,7 @@ class QualityEvaluator:
             return counts, regs
 
         shard_rows = P(axes)  # rows split over every axis (pure DP)
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             dist_pass, mesh=mesh,
             in_specs=(shard_rows,),
             out_specs=(P(), {s: P() for s, _ in pln.sketch_specs}),
@@ -210,13 +204,18 @@ class QualityEvaluator:
         per_device = 8 if self.backend in ("pallas", "fused_scan") else 1
         return self._shard_count() * per_device
 
+    def _sharding(self):
+        """Leading axis split over every mesh axis; None (the default
+        device) without a mesh."""
+        if self.mesh is None:
+            return None
+        return NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
+
     def device_planes(self, tensor: TripleTensor):
+        # host NumPy goes straight to its shards: no staging copy of the
+        # whole chunk on one device
         padded = tensor.padded_to(max(1, self._row_multiple()))
-        arr = jnp.asarray(padded.planes)
-        if self.mesh is not None:
-            sharding = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
-            arr = jax.device_put(arr, sharding)
-        return arr
+        return jax.device_put(padded.planes, self._sharding())
 
     # -- public API ------------------------------------------------------------
     def assess(self, tensor: TripleTensor) -> AssessmentResult:
@@ -296,7 +295,7 @@ class QualityEvaluator:
         if self.mesh is None:
             return jax.jit(batch_pass)
         shard_batch = P(tuple(self.mesh.axis_names))
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             batch_pass, mesh=self.mesh,
             in_specs=(shard_batch,),
             out_specs=(shard_batch,
@@ -327,19 +326,17 @@ class QualityEvaluator:
         stack = np.zeros((len(tensors) + pad_b, rows, N_PLANES), np.int32)
         for i, t in enumerate(tensors):
             stack[i, :t.n_rows] = t.planes
-        arr = jnp.asarray(stack)
-        if self.mesh is not None:
-            arr = jax.device_put(arr, NamedSharding(
-                self.mesh, P(tuple(self.mesh.axis_names))))
-        outs = [fn(arr) for fn in self._batch_pass_fns]
-        results = []
-        for i in range(len(tensors)):
-            counts = [np.asarray(c[i], np.int64) for c, _ in outs]
-            regs: dict = {}
-            for _, r in outs:
-                regs.update({k: np.asarray(v[i]) for k, v in r.items()})
-            results.append((counts, regs))
-        return results
+        arr = jax.device_put(stack, self._sharding())
+        # each batched output comes to the host once and is split there:
+        # indexing the batch-sharded device array per segment would be one
+        # device op and transfer per segment, and is a ShardingTypeError
+        # on a mesh with explicit axes
+        outs = [(np.asarray(c, np.int64),
+                 {k: np.asarray(v) for k, v in r.items()})
+                for c, r in (fn(arr) for fn in self._batch_pass_fns)]
+        return [([c[i] for c, _ in outs],
+                 {k: v[i] for _, r in outs for k, v in r.items()})
+                for i in range(len(tensors))]
 
     @staticmethod
     def merge_chunk(state: dict, chunk_id: int, counts, regs) -> dict:

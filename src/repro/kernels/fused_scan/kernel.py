@@ -24,6 +24,9 @@ TPU mapping notes:
   throughput.
 * program/sketch specs are STATIC Python tuples — everything is unrolled at
   trace time; no dynamic control flow in the kernel.
+* ``vmem_bytes`` is the block's scoped-VMEM model; the ops wrapper takes
+  the largest power-of-two BLOCK_N it fits (``kernels.block_rows``).  For
+  the ``all`` plan that is 1024 rows at p=12 and at p=14.
 """
 from __future__ import annotations
 
@@ -33,7 +36,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import LANE_ROW_BYTES, onehot_row_cap
 from ..hll.kernel import _bucket_rank, _fmix32
+from ..qap_count import kernel as qap_kernel
 from ..qap_count.kernel import COUNTS_WIDTH, _eval_block
 
 HASH_SALT = 0x9E3779B9  # same seed as core/sketches.py and kernels/hll
@@ -43,6 +48,24 @@ def _regs_block_shape(p: int) -> tuple[int, int]:
     """Lane-aligned (rows, lanes) layout for 2^p int32 registers."""
     m = 1 << p
     return (max(m // 128, 1), min(m, 128))
+
+
+def vmem_bytes(program, sketch_cols, p: int, rows: int) -> int:
+    """Scoped VMEM for a ``rows``-row block.  The counter phase and the
+    sketch phase reuse each other's buffers (the compiler gives the
+    ``all`` plan at 2048 rows the same figure as the counter kernel
+    alone), so the need is the larger of the two: the counter stack
+    machine's (``qap_count.kernel.vmem_bytes``), and the sketch phase's
+    lane-padded row values — the double-buffered input block, the salt and
+    one murmur state per distinct column prefix, a hash, bucket and rank
+    per sketch, the invalid mask — beside one (rows_tile, 2^p) one-hot
+    tile."""
+    prefixes = {tuple(c[:i]) for c in sketch_cols
+                for i in range(1, len(c) + 1)}
+    slices = 2 + 1 + len(prefixes) + 3 * len(sketch_cols) + 1
+    rows_tile = min(rows, onehot_row_cap(p))
+    sketch = rows * LANE_ROW_BYTES * slices + rows_tile * (4 << p)
+    return max(qap_kernel.vmem_bytes(program, rows), sketch)
 
 
 def _sketch_update(block, cols, p, invalid, rows_tile, hash_states):
@@ -112,8 +135,7 @@ def _kernel(planes_ref, counts_ref, *regs_refs, program, n_counters,
     static_argnames=("program", "n_counters", "sketch_cols", "p",
                      "valid_plane", "block_n", "rows_tile", "interpret"))
 def fused_scan_kernel(planes, *, program, n_counters, sketch_cols, p,
-                      valid_plane, block_n=8192, rows_tile=256,
-                      interpret=True):
+                      valid_plane, block_n, rows_tile, interpret):
     """planes: (N, P) int32 with N % block_n == 0 →
     ((COUNTS_WIDTH,) int32 counts, tuple of (2^p,) int32 register banks,
     one per entry of ``sketch_cols``)."""

@@ -73,8 +73,7 @@ def _kernel(planes_ref, regs_ref, *, cols, p, valid_plane):
 @functools.partial(
     jax.jit,
     static_argnames=("cols", "p", "valid_plane", "block_n", "interpret"))
-def hll_fold_kernel(planes, *, cols, p, valid_plane=None, block_n=1024,
-                    interpret=True):
+def hll_fold_kernel(planes, *, cols, p, valid_plane, block_n, interpret):
     """planes: (N, P) int32, N % block_n == 0 → (2^p,) int32 registers."""
     n, width = planes.shape
     assert n % block_n == 0, (n, block_n)

@@ -1,10 +1,9 @@
 """jit'd wrapper for the HLL fold kernel."""
 from __future__ import annotations
 
-import jax.numpy as jnp
-
 from ...rdf.triple_tensor import COL_S_FLAGS
-from .. import ONEHOT_VMEM_BYTES, onehot_row_cap, record_scan
+from .. import (ONEHOT_VMEM_BYTES, fit_block, interpret_mode, onehot_row_cap,
+               record_scan)
 from .kernel import hll_fold_kernel
 
 
@@ -16,21 +15,18 @@ def bounded_block_n(p: int, block_n: int) -> int:
 
 
 def hll_fold(planes, cols: tuple[int, ...], p: int, *,
-             block_n: int = 1024, interpret: bool = True):
+             block_n: int = 1024, interpret: bool | None = None):
     """Fold (N, P) planes into (2^p,) HLL registers.
 
     Row validity is derived from the s_flags plane directly (zero ⇒ padding
     row), avoiding a second streamed input; this matches the jnp path's
-    ``valid = planes[:, COL_S_FLAGS] != 0``.
+    ``valid = planes[:, COL_S_FLAGS] != 0``.  ``interpret=None`` takes
+    the platform's choice.
     """
     record_scan(1)
-    block_n = bounded_block_n(p, block_n)
-    n = planes.shape[0]
-    if n < block_n:
-        block_n = max(8, ((n + 7) // 8) * 8)
-    pad = (-n) % block_n
-    if pad:
-        planes = jnp.pad(planes, ((0, pad), (0, 0)))
+    planes, block_n = fit_block(planes, bounded_block_n(p, block_n))
+    if interpret is None:
+        interpret = interpret_mode()
     return hll_fold_kernel(planes, cols=tuple(cols), p=p,
                            valid_plane=COL_S_FLAGS, block_n=block_n,
                            interpret=interpret)

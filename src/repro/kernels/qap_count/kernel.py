@@ -8,10 +8,15 @@ accumulate K partial counts in a VMEM accumulator that lives across grid
 steps. One data pass for all metrics (vs. the paper's one pass per metric).
 
 TPU mapping notes:
-* block = (BLOCK_N, N_PLANES) int32; BLOCK_N defaults to 8192 rows →
-  8192×10×4B = 320 KiB per block in VMEM, well under v5e's 128 MiB/core VMEM
-  budget even with the unrolled mask stack (stack_depth × 32 KiB int-mask
-  scratch), and row counts are multiples of the (8,128) int32 tile.
+* block = (BLOCK_N, N_PLANES) int32.  In VMEM its 13 columns pad to the
+  128 lanes of a (8, 128) int32 tile, and so does every (BLOCK_N, 1)
+  column slice and mask the stack machine makes: each costs 512 B per row.
+  ``vmem_bytes`` counts what the kernel holds at once — the double-buffered
+  input block, one slice per plane the program reads, the stack, the VALID
+  mask and one temporary — and the ops wrapper takes the largest
+  power-of-two BLOCK_N that fits the compiler's scoped VMEM
+  (``kernels.block_rows``).  For the ``all`` plan (8 planes, depth 3) that
+  is 15 slices, 7.5 KiB per row, and BLOCK_N = 2048.
 * the bytecode is STATIC (a Python tuple) — the stack machine is fully
   unrolled at trace time; there is no dynamic control flow in the kernel.
 * the counter accumulator is a (1, COUNTS_WIDTH) int32 VMEM block with a
@@ -28,9 +33,28 @@ from jax.experimental import pallas as pl
 
 from ...core.expr import (OP_AND, OP_ANYBITS, OP_EMIT, OP_EQ, OP_EQP, OP_GE,
                           OP_GT, OP_HASBITS, OP_LE, OP_LT, OP_NE, OP_NOT,
-                          OP_OR)
+                          OP_OR, program_stack_depth)
+from .. import LANE_ROW_BYTES
 
 COUNTS_WIDTH = 128  # lane-aligned counter row; supports up to 128 counters
+
+
+def vmem_slices(program) -> int:
+    """Lane-padded (rows, 1) values the stack machine holds at once: the
+    double-buffered input block (2), one slice per plane ``program``
+    reads, its stack, the VALID mask and one temporary."""
+    planes = set()
+    for op, a, b in program:
+        if op not in (OP_AND, OP_OR, OP_NOT, OP_EMIT):
+            planes.add(a)
+            if op == OP_EQP:
+                planes.add(b)
+    return 2 + len(planes) + program_stack_depth(program) + 2
+
+
+def vmem_bytes(program, rows: int) -> int:
+    """Scoped VMEM the kernel needs for a ``rows``-row block."""
+    return rows * LANE_ROW_BYTES * vmem_slices(program)
 
 
 def _eval_block(block, program, n_counters):
@@ -102,8 +126,7 @@ def _kernel(planes_ref, counts_ref, *, program, n_counters):
 @functools.partial(
     jax.jit,
     static_argnames=("program", "n_counters", "block_n", "interpret"))
-def fused_count_kernel(planes, *, program, n_counters, block_n=8192,
-                       interpret=True):
+def fused_count_kernel(planes, *, program, n_counters, block_n, interpret):
     """planes: (N, P) int32 with N % block_n == 0 → (COUNTS_WIDTH,) int32."""
     n, p = planes.shape
     assert n % block_n == 0, (n, block_n)

@@ -195,6 +195,8 @@ def main(argv=None):
                     help="dataset root for --serve: one segment-store "
                          "directory per registered dataset under DIR")
     args = ap.parse_args(argv)
+    from . import enable_compile_cache
+    enable_compile_cache()
 
     if args.serve is not None:
         if not args.store_root:

@@ -251,6 +251,8 @@ def main(argv=None):
     f.set_defaults(fn=_cmd_fsck)
 
     args = ap.parse_args(argv)
+    from . import enable_compile_cache
+    enable_compile_cache()
     return args.fn(args)
 
 

@@ -87,7 +87,9 @@ def main(argv=None):
                          "POST /assess still work)")
     args = ap.parse_args(argv)
 
+    from repro.launch import enable_compile_cache
     from repro.serve import QAServer, ServerConfig
+    enable_compile_cache()
 
     cfg = ServerConfig(
         store_root=args.store_root, metrics=args.metrics,

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import compat
 from ..dist.sharding import ShardingPolicy
 from .common import (apply_rope, attend, causal_mask, rmsnorm, rope_freqs,
                      softmax_xent, swiglu)
@@ -529,7 +528,7 @@ def _moe_ffn(cfg: TransformerConfig, p, x, mesh: Optional[Mesh],
         aux = jax.lax.pmean(aux, "model")
         return y.reshape(xb.shape), aux
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(batch_axes), P(), P("model"), P("model"), P("model")),
         out_specs=(P(batch_axes), P()),

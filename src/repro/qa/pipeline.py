@@ -21,7 +21,6 @@ import functools
 import os
 from typing import Any, Iterable, Optional, Sequence, Union
 
-from .. import compat
 from ..core.evaluator import (AssessmentResult, QualityEvaluator,
                               run_single_shot)
 from ..core.metrics import (ALL_METRICS, EXTENDED_METRICS, PAPER_METRICS,
@@ -52,7 +51,6 @@ class ExecutionConfig:
     chunks: int = 0                    # 0 = single shot
     checkpoint_dir: Optional[str] = None
     checkpoint_every: int = 8
-    interpret: bool = True             # pallas interpret mode (CPU hosts)
     hll_p: int = hll.DEFAULT_P
     stream_triples: int = 0            # >0: streaming ingest chunk size
     prefetch: int = 0                  # >0: async pipelined chunk executor
@@ -133,7 +131,9 @@ class _MeshKey:
 
     def __init__(self, mesh):
         self.mesh = mesh
-        self.key = compat.mesh_structural_key(mesh)
+        self.key = None if mesh is None else (
+            tuple(mesh.axis_names), tuple(mesh.devices.shape),
+            tuple(d.id for d in mesh.devices.flat))
 
     def __hash__(self):
         return hash(self.key)
@@ -144,8 +144,7 @@ class _MeshKey:
 
 @functools.lru_cache(maxsize=16)
 def _evaluator_for(metrics_key: tuple, backend: str, fused: bool,
-                   mesh_key: _MeshKey, hll_p: int,
-                   interpret: bool) -> QualityEvaluator:
+                   mesh_key: _MeshKey, hll_p: int) -> QualityEvaluator:
     # keyed on the Metric OBJECTS (not names), so re-registering a name
     # yields a fresh engine rather than a stale cached plan, and ONLY on
     # the engine-relevant exec fields — scheduler-only settings (chunks,
@@ -153,8 +152,7 @@ def _evaluator_for(metrics_key: tuple, backend: str, fused: bool,
     # wrapped in _MeshKey (structural identity): the first mesh seen for
     # a given structure is the one the cached engine keeps using.
     return QualityEvaluator([m.name for m in metrics_key], fused=fused,
-                            backend=backend, mesh=mesh_key.mesh, hll_p=hll_p,
-                            interpret=interpret)
+                            backend=backend, mesh=mesh_key.mesh, hll_p=hll_p)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -262,9 +260,6 @@ class Pipeline:
         return self._exec(chunks=0, checkpoint_dir=None, stream_triples=0,
                           store_dir=None)
 
-    def interpret(self, flag: bool) -> "Pipeline":
-        return self._exec(interpret=flag)
-
     def hll(self, p: int) -> "Pipeline":
         return self._exec(hll_p=p)
 
@@ -280,7 +275,7 @@ class Pipeline:
         metrics_key = tuple(REGISTRY[n] for n in self.metric_names)
         e = self.exec
         return _evaluator_for(metrics_key, e.backend, e.fused,
-                              _MeshKey(e.mesh), e.hll_p, e.interpret)
+                              _MeshKey(e.mesh), e.hll_p)
 
     def run(self, dataset: Dataset) -> AssessmentResult:
         """Ingest ``dataset`` and execute; chunked/streaming runs attach a

@@ -15,8 +15,11 @@ def rng():
 
 
 def run_subprocess_devices(n_devices: int, code: str) -> dict:
-    """Run `code` with n fake XLA devices; it must print one JSON line."""
+    """Run `code` with n fake XLA devices; it must print one JSON line.
+    The child is pinned to the CPU: these are CPU rehearsals, and on a
+    machine with an accelerator the child must not try to take it."""
     env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": f"--xla_force_host_platform_device_count={n_devices}",
            "PYTHONPATH": "src"}
     proc = subprocess.run([sys.executable, "-c", code], env=env,

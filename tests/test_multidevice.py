@@ -232,8 +232,7 @@ from jax.sharding import PartitionSpec as P
 from repro.dist import compressed_psum
 from repro.launch.mesh import make_host_mesh
 mesh = make_host_mesh()
-from repro import compat
-g = jax.jit(compat.shard_map(lambda x, e: compressed_psum(x, 'data', e),
+g = jax.jit(jax.shard_map(lambda x, e: compressed_psum(x, 'data', e),
     mesh=mesh, in_specs=(P('data'), P('data')), out_specs=(P(), P('data'))))
 x = np.random.default_rng(0).normal(size=(64, 32)).astype(np.float32)
 true = x.reshape(8, 8, 32).mean(0)
