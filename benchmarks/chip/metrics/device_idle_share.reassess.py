@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - union of operation intervals / window), in re-assessments of
+encoded planes."""
+
+
+def read(run):
+    return None if run.trace is None else run.trace.idle_share()

@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - union of operation intervals / window), under changesets."""
+
+
+def read(run):
+    return None if run.trace is None else run.trace.idle_share()
