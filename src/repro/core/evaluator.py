@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import spans
 from ..kernels import count_scans, record_scan
 from ..rdf.triple_tensor import TripleTensor, COL_S_FLAGS, N_PLANES
 from . import sketches as hll
@@ -61,6 +62,7 @@ class AssessmentResult:
     n_triples: int
     passes: int                         # ACTUAL data passes performed
     exec_stats: object = None           # dist.ChunkStats when run chunked
+    trace: object = None                # spans.Recorder of the run
     # merged HLL register banks (sketch name -> int32 array); exposed so
     # exactness can be asserted at the register level, not just on the
     # derived estimates
@@ -213,9 +215,14 @@ class QualityEvaluator:
 
     def device_planes(self, tensor: TripleTensor):
         # host NumPy goes straight to its shards: no staging copy of the
-        # whole chunk on one device
-        padded = tensor.padded_to(max(1, self._row_multiple()))
-        return jax.device_put(padded.planes, self._sharding())
+        # whole chunk on one device.  The span ends when the copy has
+        # landed, so it times the transfer and not its enqueue.
+        with spans.span("scan.transfer"):
+            padded = tensor.padded_to(max(1, self._row_multiple()))
+            arr = jax.device_put(padded.planes, self._sharding())
+            arr.block_until_ready()
+        spans.count("transfer.bytes", padded.planes.nbytes)
+        return arr
 
     # -- public API ------------------------------------------------------------
     def assess(self, tensor: TripleTensor) -> AssessmentResult:
@@ -254,12 +261,14 @@ class QualityEvaluator:
         """Launch every plan's pass over device-resident ``arr`` WITHOUT
         blocking (JAX dispatch is async) — the device-side half of
         ``eval_chunk``.  Pair with ``materialize_chunk``."""
-        return [fn(arr) for fn in self._pass_fns]
+        with spans.span("scan.dispatch"):
+            return [fn(arr) for fn in self._pass_fns]
 
     @staticmethod
     def materialize_chunk(outs):
         """Block until the dispatched passes finish and gather host numpy
-        results — the single per-chunk host synchronization point."""
+        results — the single per-chunk host synchronization point.
+        Callers time it as ``scan.wait``."""
         counts_out, regs_out = [], {}
         for counts, regs in outs:
             counts_out.append(np.asarray(counts, np.int64))
@@ -268,7 +277,9 @@ class QualityEvaluator:
 
     def eval_chunk(self, chunk: TripleTensor):
         arr = self.device_planes(chunk)
-        return self.materialize_chunk(self.dispatch_chunk(arr))
+        outs = self.dispatch_chunk(arr)
+        with spans.span("scan.wait"):
+            return self.materialize_chunk(outs)
 
     # -- batched independent segments (mesh scale-out of incremental runs) -----
     def _batch_pass_fn(self, pln: Plan):
@@ -323,17 +334,24 @@ class QualityEvaluator:
             return []
         pad_b = (-len(tensors)) % self._shard_count()
         rows = max(8, max(((t.n_rows + 7) // 8) * 8 for t in tensors))
-        stack = np.zeros((len(tensors) + pad_b, rows, N_PLANES), np.int32)
-        for i, t in enumerate(tensors):
-            stack[i, :t.n_rows] = t.planes
-        arr = jax.device_put(stack, self._sharding())
+        with spans.span("scan.transfer"):
+            stack = np.zeros((len(tensors) + pad_b, rows, N_PLANES),
+                             np.int32)
+            for i, t in enumerate(tensors):
+                stack[i, :t.n_rows] = t.planes
+            arr = jax.device_put(stack, self._sharding())
+            arr.block_until_ready()
+        spans.count("transfer.bytes", stack.nbytes)
+        with spans.span("scan.dispatch"):
+            dispatched = [fn(arr) for fn in self._batch_pass_fns]
         # each batched output comes to the host once and is split there:
         # indexing the batch-sharded device array per segment would be one
         # device op and transfer per segment, and is a ShardingTypeError
         # on a mesh with explicit axes
-        outs = [(np.asarray(c, np.int64),
-                 {k: np.asarray(v) for k, v in r.items()})
-                for c, r in (fn(arr) for fn in self._batch_pass_fns)]
+        with spans.span("scan.wait"):
+            outs = [(np.asarray(c, np.int64),
+                     {k: np.asarray(v) for k, v in r.items()})
+                    for c, r in dispatched]
         return [([c[i] for c, _ in outs],
                  {k: v[i] for _, r in outs for k, v in r.items()})
                 for i in range(len(tensors))]
@@ -343,23 +361,26 @@ class QualityEvaluator:
         """Idempotent merge — re-delivered chunks are ignored."""
         if chunk_id in state["chunks_done"]:
             return state
-        state["counts"] = [a + b for a, b in zip(state["counts"], counts)]
-        for k, v in regs.items():
-            state["sketches"][k] = np.maximum(state["sketches"][k], v)
-        state["chunks_done"].add(chunk_id)
+        with spans.span("scan.merge"):
+            state["counts"] = [a + b
+                               for a, b in zip(state["counts"], counts)]
+            for k, v in regs.items():
+                state["sketches"][k] = np.maximum(state["sketches"][k], v)
+            state["chunks_done"].add(chunk_id)
         return state
 
     def finalize_state(self, state: dict, n_triples: int) -> AssessmentResult:
-        est = {"sketch:" + k: float(hll.hll_estimate(jnp.asarray(v)))
-               for k, v in state["sketches"].items()}
-        values: dict[str, float] = {}
-        counts_out: dict[str, dict[str, int]] = {}
-        for pln, counts in zip(self.plans, state["counts"]):
-            values.update(pln.finalize(counts, est))
-            for m in pln.metrics:
-                counts_out[m.name] = {
-                    c: int(counts[pln.slots[m.name][c]])
-                    for c, _ in m.counters}
+        with spans.span("scan.finalize"):
+            est = {"sketch:" + k: float(hll.hll_estimate(jnp.asarray(v)))
+                   for k, v in state["sketches"].items()}
+            values: dict[str, float] = {}
+            counts_out: dict[str, dict[str, int]] = {}
+            for pln, counts in zip(self.plans, state["counts"]):
+                values.update(pln.finalize(counts, est))
+                for m in pln.metrics:
+                    counts_out[m.name] = {
+                        c: int(counts[pln.slots[m.name][c]])
+                        for c, _ in m.counters}
         return AssessmentResult(values=values, counts=counts_out,
                                 sketch_estimates=est, n_triples=n_triples,
                                 passes=len(state["chunks_done"])
@@ -378,7 +399,10 @@ def run_single_shot(evaluator: QualityEvaluator,
     single-shot and chunked execution share one finalize path and cannot
     drift apart.
     """
-    state = evaluator.chunk_state_init()
-    counts, regs = evaluator.eval_chunk(tensor)
-    state = QualityEvaluator.merge_chunk(state, 0, counts, regs)
-    return evaluator.finalize_state(state, len(tensor))
+    with spans.run() as rec:
+        state = evaluator.chunk_state_init()
+        counts, regs = evaluator.eval_chunk(tensor)
+        state = QualityEvaluator.merge_chunk(state, 0, counts, regs)
+        result = evaluator.finalize_state(state, len(tensor))
+    result.trace = rec
+    return result

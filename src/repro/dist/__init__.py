@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..checkpoint import CheckpointManager
 from .sharding import ShardingPolicy, split_params
 
@@ -119,10 +120,13 @@ class ChunkStats:
     checkpoints_written: int = 0
     mode: str = "sync"           # "sync" | "pipelined"
     passes_per_chunk: int = 0    # actual HBM data passes per chunk eval
-    wall_seconds: float = 0.0    # end-to-end run() wall time
-    # per merged chunk, host-observed seconds: full eval (sync mode) or
-    # time blocked in the deferred materialization (pipelined mode — the
-    # overlap headroom is exactly what's NOT in here)
+    # host seconds of the run up to its finalize: the ``scan.schedule``
+    # span (``store.assess`` in incremental runs)
+    wall_seconds: float = 0.0
+    # per merged chunk, host-observed seconds: the ``scan.eval`` span of
+    # the full eval (sync mode) or the ``scan.wait`` span of the deferred
+    # materialization (pipelined mode — the overlap headroom is exactly
+    # what's NOT in here)
     chunk_eval_seconds: list = dataclasses.field(default_factory=list)
     # chunk ids whose eval time exceeded straggler_factor × the running
     # median of chunk_eval_seconds (see ChunkScheduler.straggler_factor)
@@ -270,7 +274,16 @@ class ChunkScheduler:
         ``dataset``: a ``TripleTensor`` (split into ``n_chunks`` here) or an
         already-chunked sequence of ``TripleTensor``s (streaming ingest).
         """
-        t0 = time.perf_counter()
+        with spans.run() as rec:
+            with spans.span("scan.schedule") as sp:
+                state, n_triples, stats = self._schedule(dataset, faults)
+            stats.wall_seconds = sp.seconds
+            result = self.evaluator.finalize_state(state, n_triples)
+        result.trace = rec
+        return result, stats
+
+    def _schedule(self, dataset, faults: Optional[FaultInjector]):
+        """Evaluate and merge every chunk: (state, n_triples, stats)."""
         ev = self.evaluator
         if hasattr(dataset, "chunks"):
             chunks: Iterable = dataset.chunks(self.n_chunks)
@@ -311,9 +324,8 @@ class ChunkScheduler:
                 f"straggler chunks {stats.stragglers}: eval exceeded "
                 f"{self.straggler_factor}x the running median of "
                 f"{len(stats.chunk_eval_seconds)} chunk eval times",
-                RuntimeWarning, stacklevel=2)
-        stats.wall_seconds = time.perf_counter() - t0
-        return ev.finalize_state(state, n_triples), stats
+                RuntimeWarning, stacklevel=3)
+        return state, n_triples, stats
 
     # -- shared loop pieces ----------------------------------------------------
     def _skip_done(self, state: dict, cid: int, n: int) -> bool:
@@ -452,15 +464,16 @@ class ChunkScheduler:
             if self._skip_done(state, cid, len(chunk)):
                 continue
             self._chunk_sizes[cid] = len(chunk)
-            t0 = time.perf_counter()
             eval_once = functools.partial(ev.eval_chunk, chunk)
             threshold = self._speculation_threshold(stats)
-            if threshold is None:
-                counts, regs = self._attempt(eval_once, cid, stats, faults)
-            else:
-                counts, regs = self._eval_speculative(
-                    eval_once, cid, stats, faults, threshold)
-            self._note_eval_time(cid, time.perf_counter() - t0, stats)
+            with spans.span("scan.eval") as sp:
+                if threshold is None:
+                    counts, regs = self._attempt(eval_once, cid, stats,
+                                                 faults)
+                else:
+                    counts, regs = self._eval_speculative(
+                        eval_once, cid, stats, faults, threshold)
+            self._note_eval_time(cid, sp.seconds, stats)
             self._merge_and_checkpoint(state, cid, counts, regs, stats,
                                        faults)
         return n_triples
@@ -480,6 +493,7 @@ class ChunkScheduler:
         q: queue_mod.Queue = queue_mod.Queue(maxsize=max(1, self.prefetch))
         stop = threading.Event()
         done_at_start = frozenset(state["chunks_done"])
+        run = spans.handle()        # the producer records into this run
 
         def _put(item) -> bool:
             while not stop.is_set():
@@ -492,11 +506,12 @@ class ChunkScheduler:
 
         def produce():
             try:
-                for cid, chunk in enumerate(chunks):
-                    arr = (None if cid in done_at_start
-                           else ev.device_planes(chunk))
-                    if not _put((cid, len(chunk), arr)):
-                        return
+                with spans.attach(run):
+                    for cid, chunk in enumerate(chunks):
+                        arr = (None if cid in done_at_start
+                               else ev.device_planes(chunk))
+                        if not _put((cid, len(chunk), arr)):
+                            return
                 _put(_END_OF_STREAM)
             except BaseException as e:  # relay ingest failures
                 _put(_ProducerError(e))
@@ -508,7 +523,8 @@ class ChunkScheduler:
         pending = None  # (cid, dispatched-but-unmaterialized outputs)
         try:
             while True:
-                item = q.get()
+                with spans.span("scan.feed_wait"):
+                    item = q.get()
                 if isinstance(item, _ProducerError):
                     raise item.exc
                 if item is _END_OF_STREAM:
@@ -546,22 +562,23 @@ class ChunkScheduler:
         # where the whole eval (dispatch + sync) sits inside the retry loop
         ev = self.evaluator
         cid, outs, arr, used = pending
-        t0 = time.perf_counter()
-        try:
-            counts, regs = ev.materialize_chunk(outs)
-        except WorkerFailure:
-            # the dispatch that produced ``outs`` was attempt number
-            # ``used``; its materialization failing fails THAT attempt, so
-            # the recovery budget is what's left of max_attempts — a chunk
-            # aborts after the same total failures as in _run_sync no
-            # matter where in dispatch/materialize they strike
-            stats.retries += 1
-            if self.max_attempts - used <= 0:
-                raise
-            counts, regs = self._attempt(
-                lambda: ev.materialize_chunk(ev.dispatch_chunk(arr)),
-                cid, stats, faults, budget=self.max_attempts - used)
-        self._note_eval_time(cid, time.perf_counter() - t0, stats)
+        with spans.span("scan.wait") as sp:
+            try:
+                counts, regs = ev.materialize_chunk(outs)
+            except WorkerFailure:
+                # the dispatch that produced ``outs`` was attempt number
+                # ``used``; its materialization failing fails THAT
+                # attempt, so the recovery budget is what's left of
+                # max_attempts — a chunk aborts after the same total
+                # failures as in _run_sync no matter where in
+                # dispatch/materialize they strike
+                stats.retries += 1
+                if self.max_attempts - used <= 0:
+                    raise
+                counts, regs = self._attempt(
+                    lambda: ev.materialize_chunk(ev.dispatch_chunk(arr)),
+                    cid, stats, faults, budget=self.max_attempts - used)
+        self._note_eval_time(cid, sp.seconds, stats)
         self._merge_and_checkpoint(state, cid, counts, regs, stats, faults)
 
 

@@ -21,6 +21,7 @@ import functools
 import os
 from typing import Any, Iterable, Optional, Sequence, Union
 
+from .. import spans
 from ..core.evaluator import (AssessmentResult, QualityEvaluator,
                               run_single_shot)
 from ..core.metrics import (ALL_METRICS, EXTENDED_METRICS, PAPER_METRICS,
@@ -279,7 +280,14 @@ class Pipeline:
 
     def run(self, dataset: Dataset) -> AssessmentResult:
         """Ingest ``dataset`` and execute; chunked/streaming runs attach a
-        ``dist.ChunkStats`` on ``result.exec_stats``."""
+        ``dist.ChunkStats`` on ``result.exec_stats``, and every run its
+        ``spans.Recorder`` on ``result.trace``."""
+        with spans.run() as rec:
+            result = self._run(dataset)
+        result.trace = rec
+        return result
+
+    def _run(self, dataset: Dataset) -> AssessmentResult:
         if self.exec.store_dir:
             return self._run_incremental(dataset)
         data = self.ingest(dataset)

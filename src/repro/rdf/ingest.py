@@ -49,6 +49,7 @@ from typing import BinaryIO, Iterator, Optional, Sequence, Union
 
 import numpy as np
 
+from ..spans import count, span
 from . import vocab
 from .encoder import TermDictionary
 from .parser import escape_literal, parse_ntriples
@@ -558,48 +559,54 @@ def _encode_block(data: bytes, dictionary: TermDictionary) -> np.ndarray:
     """
     if not data:
         return np.zeros((0, N_PLANES), np.int32)
-    scan = _Scan(data)
-    if scan.buf.max() >= 0x80:
-        # match the reference path's contract (it only ever sees decoded
-        # text): invalid UTF-8 fails loudly at ingest, not via a poisoned
-        # dictionary or a deep per-line decode. Blocks are split on line
-        # boundaries and multi-byte sequences never contain 0x0A, so block
-        # edges cannot cut a character.
-        data.decode("utf-8")
-    start, end, lo, hi, forced_fb = _line_table(scan)
-    ok, spans = _fast_spans(scan, lo, hi, forced_fb)
+    with span("ingest.tokenize"):
+        scan = _Scan(data)
+        if scan.buf.max() >= 0x80:
+            # match the reference path's contract (it only ever sees
+            # decoded text): invalid UTF-8 fails loudly at ingest, not via
+            # a poisoned dictionary or a deep per-line decode. Blocks are
+            # split on line boundaries and multi-byte sequences never
+            # contain 0x0A, so block edges cannot cut a character.
+            data.decode("utf-8")
+        start, end, lo, hi, forced_fb = _line_table(scan)
+        ok, spans = _fast_spans(scan, lo, hi, forced_fb)
     L = lo.size
 
     # reference path for everything the fast path is not sure about; owns
     # comment/blank re-splitting and the malformed-line sentinel semantics
     fb_rows = np.flatnonzero(~ok)
+    count("ingest.bytes", len(data))
+    count("ingest.lines", L)
+    count("ingest.fallback_lines", fb_rows.size)
     fb_counts = np.zeros(fb_rows.size, np.int64)
     fb_terms = []
-    for j, r in enumerate(fb_rows):
-        triples = parse_ntriples(data[start[r]:end[r]].decode("utf-8"))
-        fb_counts[j] = len(triples)
-        for s, p, o in triples:
-            fb_terms.append(s)
-            fb_terms.append(p)
-            fb_terms.append(o)
+    with span("ingest.fallback"):
+        for j, r in enumerate(fb_rows):
+            triples = parse_ntriples(data[start[r]:end[r]].decode("utf-8"))
+            fb_counts[j] = len(triples)
+            for s, p, o in triples:
+                fb_terms.append(s)
+                fb_terms.append(p)
+                fb_terms.append(o)
 
     # batch-dedup fast tokens → classes 0..U-1, with vectorized metadata
     fast_spans = spans[ok].reshape(-1, 2)
     rekeyed = False
     if fast_spans.shape[0]:
-        tiers, inv = _dedup_tokens(data, fast_spans)
-        keys_l, flags_l, lengths_l, dts_l = [], [], [], []
-        for umat, ulen in tiers:
-            k, f, ln, dt, rk = _unique_metadata(umat, ulen, dictionary)
-            keys_l.extend(k)
-            flags_l.append(f)
-            lengths_l.append(ln)
-            dts_l.append(dt)
-            rekeyed = rekeyed or rk
-        class_keys = keys_l
-        fast_flags = np.concatenate(flags_l)
-        fast_lengths = np.concatenate(lengths_l)
-        fast_dts = np.concatenate(dts_l)
+        with span("ingest.dedup"):
+            tiers, inv = _dedup_tokens(data, fast_spans)
+            keys_l, flags_l, lengths_l, dts_l = [], [], [], []
+            for umat, ulen in tiers:
+                k, f, ln, dt, rk = _unique_metadata(umat, ulen, dictionary)
+                keys_l.extend(k)
+                flags_l.append(f)
+                lengths_l.append(ln)
+                dts_l.append(dt)
+                rekeyed = rekeyed or rk
+            class_keys = keys_l
+            fast_flags = np.concatenate(flags_l)
+            fast_lengths = np.concatenate(lengths_l)
+            fast_dts = np.concatenate(dts_l)
     else:
         inv = np.zeros(0, np.int64)
         class_keys = []
@@ -611,69 +618,74 @@ def _encode_block(data: bytes, dictionary: TermDictionary) -> np.ndarray:
     # transform (e.g. ""^^<> → "") can alias two distinct fast tokens, so
     # build the canonicalization map whenever either source of duplicate
     # keys exists (token↔key is bijective otherwise)
-    fb_class = np.empty(len(fb_terms), np.int32)
-    fb_flags, fb_lengths, fb_dts = [], [], []
-    canon = None
-    if fb_terms or rekeyed:
-        key_to_class: dict[bytes, int] = {}
-        canon = np.arange(len(class_keys) + len(fb_terms), dtype=np.int32)
-        for i, k in enumerate(class_keys):
-            j = key_to_class.setdefault(k, i)
-            if j != i:
-                canon[i] = j
-        for i, t in enumerate(fb_terms):
-            kb = t.key().encode("utf-8")
-            c = key_to_class.get(kb)
-            if c is None:
-                c = len(class_keys)
-                key_to_class[kb] = c
-                class_keys.append(kb)
-                f, length, dt = dictionary._term_flags(t)
-                fb_flags.append(f)
-                fb_lengths.append(length)
-                fb_dts.append(dt)
-            fb_class[i] = c
-    all_flags = np.concatenate([fast_flags, np.asarray(fb_flags, np.int32)])
-    all_lengths = np.concatenate([fast_lengths,
-                                  np.asarray(fb_lengths, np.int64)])
-    all_dts = np.concatenate([fast_dts, np.asarray(fb_dts, np.int32)])
+    with span("ingest.intern"):
+        fb_class = np.empty(len(fb_terms), np.int32)
+        fb_flags, fb_lengths, fb_dts = [], [], []
+        canon = None
+        if fb_terms or rekeyed:
+            key_to_class: dict[bytes, int] = {}
+            canon = np.arange(len(class_keys) + len(fb_terms),
+                              dtype=np.int32)
+            for i, k in enumerate(class_keys):
+                j = key_to_class.setdefault(k, i)
+                if j != i:
+                    canon[i] = j
+            for i, t in enumerate(fb_terms):
+                kb = t.key().encode("utf-8")
+                c = key_to_class.get(kb)
+                if c is None:
+                    c = len(class_keys)
+                    key_to_class[kb] = c
+                    class_keys.append(kb)
+                    f, length, dt = dictionary._term_flags(t)
+                    fb_flags.append(f)
+                    fb_lengths.append(length)
+                    fb_dts.append(dt)
+                fb_class[i] = c
+        all_flags = np.concatenate([fast_flags,
+                                    np.asarray(fb_flags, np.int32)])
+        all_lengths = np.concatenate([fast_lengths,
+                                      np.asarray(fb_lengths, np.int64)])
+        all_dts = np.concatenate([fast_dts, np.asarray(fb_dts, np.int32)])
 
-    # interleave fast and fallback triples back into line order
-    n_per_line = np.ones(L, np.int64)
-    n_per_line[fb_rows] = fb_counts
-    offsets = np.concatenate([[0], np.cumsum(n_per_line)])
-    N = int(offsets[-1])
-    if N == 0:
-        return np.zeros((0, N_PLANES), np.int32)
-    cls = np.empty((N, 3), np.int32)
-    cls[offsets[:-1][ok]] = inv.reshape(-1, 3)
-    if fb_rows.size:
-        fb_pos = np.concatenate([
-            offsets[r] + np.arange(k)
-            for r, k in zip(fb_rows, fb_counts)]).astype(np.int64)
-        cls[fb_pos] = fb_class.reshape(-1, 3)
-    if canon is not None:
-        cls = canon[cls]
+        # interleave fast and fallback triples back into line order
+        n_per_line = np.ones(L, np.int64)
+        n_per_line[fb_rows] = fb_counts
+        offsets = np.concatenate([[0], np.cumsum(n_per_line)])
+        N = int(offsets[-1])
+        if N == 0:
+            return np.zeros((0, N_PLANES), np.int32)
+        cls = np.empty((N, 3), np.int32)
+        cls[offsets[:-1][ok]] = inv.reshape(-1, 3)
+        if fb_rows.size:
+            fb_pos = np.concatenate([
+                offsets[r] + np.arange(k)
+                for r, k in zip(fb_rows, fb_counts)]).astype(np.int64)
+            cls[fb_pos] = fb_class.reshape(-1, 3)
+        if canon is not None:
+            cls = canon[cls]
 
-    # global first-appearance order over the flattened (s0,p0,o0,s1,...)
-    # sequence = the exact order the per-term intern() loop would assign ids
-    flat = cls.reshape(-1)
-    present, first_pos = np.unique(flat, return_index=True)
-    order = np.argsort(first_pos, kind="stable")
-    ordered = present[order]
-    gids = dictionary.intern_keys_batch(
-        [class_keys[c] for c in ordered.tolist()],
-        all_flags[ordered], all_lengths[ordered], all_dts[ordered])
-    class_gid = np.zeros(len(class_keys), np.int64)
-    class_gid[ordered] = gids
-    ids = class_gid[cls]
+        # global first-appearance order over the flattened
+        # (s0,p0,o0,s1,...) sequence = the exact order the per-term
+        # intern() loop would assign ids
+        flat = cls.reshape(-1)
+        present, first_pos = np.unique(flat, return_index=True)
+        order = np.argsort(first_pos, kind="stable")
+        ordered = present[order]
+        gids = dictionary.intern_keys_batch(
+            [class_keys[c] for c in ordered.tolist()],
+            all_flags[ordered], all_lengths[ordered], all_dts[ordered])
+        class_gid = np.zeros(len(class_keys), np.int64)
+        class_gid[ordered] = gids
+        ids = class_gid[cls]
 
-    flags, lengths, dts, hashes = dictionary.plane_arrays()
-    s, p, o = ids[:, 0], ids[:, 1], ids[:, 2]
-    return from_columns(s, p, o, flags[s], flags[p], flags[o],
-                        lengths[s], lengths[p], lengths[o], dts[o],
-                        s_hash=hashes[s], p_hash=hashes[p],
-                        o_hash=hashes[o]).planes
+    with span("ingest.planes"):
+        flags, lengths, dts, hashes = dictionary.plane_arrays()
+        s, p, o = ids[:, 0], ids[:, 1], ids[:, 2]
+        return from_columns(s, p, o, flags[s], flags[p], flags[o],
+                            lengths[s], lengths[p], lengths[o], dts[o],
+                            s_hash=hashes[s], p_hash=hashes[p],
+                            o_hash=hashes[o]).planes
 
 
 # --- public API ---------------------------------------------------------------
@@ -794,7 +806,8 @@ def _stream_fileobj(f: BinaryIO, chunk_triples: int, d: TermDictionary,
                             len(d))
 
     while True:
-        block = f.read(block_bytes)
+        with span("ingest.read"):
+            block = f.read(block_bytes)
         if not block:
             break
         cut = block.rfind(b"\n")

@@ -412,6 +412,21 @@ class QAServer:
                      dataset=name)
         self.obs.inc("repro_segments_rescanned_total",
                      s.segments_rescanned, dataset=name)
+        self._on_done(job, res)
+
+    def _on_done(self, job: Job, res) -> None:
+        """Export a finished job's host time by program stage (the self
+        seconds of each span name, ``compile`` among them — a fixed set,
+        so the label stays low-cardinality), the program's counters (lines,
+        fallback lines and bytes ingested, bytes sent to the device) and
+        its wait in the queue."""
+        for span, secs in sorted(res.trace.self_seconds_by_name().items()):
+            self.obs.inc("repro_span_seconds_total", secs, span=span)
+        for name, n in sorted(res.trace.counts.items()):
+            self.obs.inc("repro_program_count_total", n, counter=name)
+        if job.started_at is not None:
+            self.obs.observe("repro_job_queue_wait_seconds",
+                             job.started_at - job.enqueued_at)
 
     def _fire_alerts(self, job: Job, ts: str) -> None:
         """Evaluate the dataset's rules against this run's values, with

@@ -55,11 +55,11 @@ batched executor replaces the chunk scheduler for those rescans, so
 from __future__ import annotations
 
 import hashlib
-import time
 from typing import Iterable, Optional, Sequence
 
 import numpy as np
 
+from .. import spans
 from ..core.evaluator import AssessmentResult, QualityEvaluator
 from ..dist import ChunkScheduler, ChunkStats
 from ..rdf import TermDictionary
@@ -175,8 +175,29 @@ def assess_incremental(evaluator: QualityEvaluator,
     On success the store's manifest is committed for the new dataset
     version and a quality snapshot is appended to ``history.jsonl``.
     """
-    t0 = time.perf_counter()
-    ev = evaluator
+    with spans.run() as rec:
+        with spans.span("store.assess") as sp:
+            result, store, order = _assess(
+                evaluator, segments, store_dir, base_namespaces, prefetch,
+                straggler_factor, speculate)
+        result.exec_stats.wall_seconds = sp.seconds
+        with spans.span("store.commit"):
+            store.commit(order)
+        if history:
+            from ..core import report
+            with spans.span("store.history"):
+                store.append_history(report.history_entry(
+                    result, dataset_uri=dataset_uri),
+                    max_history=max_history)
+    result.trace = rec
+    return result
+
+
+def _assess(ev: QualityEvaluator, segments: Iterable[bytes], store_dir: str,
+            base_namespaces: Sequence[str], prefetch: int,
+            straggler_factor: float, speculate: bool):
+    """Assess the segments against the store, committing nothing:
+    (result, store, the new version's segment order)."""
     store = SegmentStore(store_dir,
                          engine_signature(ev, base_namespaces))
     d = TermDictionary(base_namespaces)
@@ -202,9 +223,12 @@ def assess_incremental(evaluator: QualityEvaluator,
         are never replayed at all (nothing downstream encodes against
         them) — warm re-crawls of many-segment stores skip the whole
         dictionary rebuild."""
-        for st in deferred:
-            d.intern_keys_batch(st.keys, st.flags, st.lengths,
-                                st.datatypes)
+        if not deferred:
+            return
+        with spans.span("store.replay"):
+            for st in deferred:
+                d.intern_keys_batch(st.keys, st.flags, st.lengths,
+                                    st.datatypes)
         replayed[0] += len(deferred)
         deferred.clear()
 
@@ -213,10 +237,17 @@ def assess_incremental(evaluator: QualityEvaluator,
         scheduler's producer thread when pipelined; all side effects are
         read only after the scheduler joins it."""
         cid = 0
-        for seg in segments:
-            fp = fingerprint(seg)
+        walk = iter(segments)
+        while True:
+            with spans.span("store.segment"):
+                seg = next(walk, None)
+            if seg is None:
+                return
+            with spans.span("store.fingerprint"):
+                fp = fingerprint(seg)
             nbytes["total"] += len(seg)
-            st = store.load_state(fp)
+            with spans.span("store.load_state"):
+                st = store.load_state(fp)
             if st is not None:
                 # The footprint replay keeps the shared dictionary
                 # canonical (cold-identical ids) for this run's rescans;
@@ -233,8 +264,9 @@ def assess_incremental(evaluator: QualityEvaluator,
                 # id-plane-reading user metric: frozen state is only
                 # valid under the exact cold id assignment, so the
                 # replay stays eager and gates reuse (PR 4 semantics)
-                ids = d.intern_keys_batch(st.keys, st.flags, st.lengths,
-                                          st.datatypes)
+                with spans.span("store.replay"):
+                    ids = d.intern_keys_batch(st.keys, st.flags,
+                                              st.lengths, st.datatypes)
                 replayed[0] += 1
                 if np.array_equal(ids, st.ids):
                     reused.append(st)
@@ -248,16 +280,17 @@ def assess_incremental(evaluator: QualityEvaluator,
             replay_deferred()
             nbytes["rescanned"] += len(seg)
             tt = rdf_ingest.parse_encode(seg, dictionary=d)
-            ids = _footprint_ids(tt.planes)
-            flags, lengths, dts, _hashes = d.plane_arrays()
+            with spans.span("store.footprint"):
+                ids = _footprint_ids(tt.planes)
+                flags, lengths, dts, _hashes = d.plane_arrays()
+                rescan_meta[cid] = {
+                    "fp": fp, "n_bytes": len(seg), "n_triples": len(tt),
+                    "keys": d.keys_for(ids), "flags": flags[ids],
+                    "lengths": lengths[ids].astype(np.int64),
+                    "datatypes": dts[ids], "ids": ids,
+                }
             order.append({"fp": fp, "n_bytes": len(seg),
                           "n_triples": len(tt)})
-            rescan_meta[cid] = {
-                "fp": fp, "n_bytes": len(seg), "n_triples": len(tt),
-                "keys": d.keys_for(ids), "flags": flags[ids],
-                "lengths": lengths[ids].astype(np.int64),
-                "datatypes": dts[ids], "ids": ids,
-            }
             cid += 1
             yield tt.padded_to(_bucket_rows(len(tt)))
 
@@ -269,13 +302,14 @@ def assess_incremental(evaluator: QualityEvaluator,
 
     def on_chunk(cid: int, counts, regs) -> None:
         m = rescan_meta.pop(cid)
-        store.put_state(SegmentState(
-            fingerprint=m["fp"], n_bytes=m["n_bytes"],
-            n_triples=m["n_triples"],
-            counts=[np.asarray(c, np.int64) for c in counts],
-            regs={k: np.asarray(v, np.int32) for k, v in regs.items()},
-            keys=m["keys"], flags=m["flags"], lengths=m["lengths"],
-            datatypes=m["datatypes"], ids=m["ids"]))
+        with spans.span("store.freeze"):
+            store.put_state(SegmentState(
+                fingerprint=m["fp"], n_bytes=m["n_bytes"],
+                n_triples=m["n_triples"],
+                counts=[np.asarray(c, np.int64) for c in counts],
+                regs={k: np.asarray(v, np.int32) for k, v in regs.items()},
+                keys=m["keys"], flags=m["flags"], lengths=m["lengths"],
+                datatypes=m["datatypes"], ids=m["ids"]))
         ev.merge_chunk(state, ("rescanned", cid), counts, regs)
         rescanned[0] += 1
 
@@ -290,7 +324,7 @@ def assess_incremental(evaluator: QualityEvaluator,
             warnings.warn(
                 "prefetch/speculate are ignored for mesh rescans: the "
                 "batched segment executor replaces the chunk scheduler",
-                RuntimeWarning, stacklevel=2)
+                RuntimeWarning, stacklevel=3)
         stats = ChunkStats(chunks_total=0, mode="incremental+mesh",
                            passes_per_chunk=ev.passes_per_chunk,
                            devices=ev._shard_count())
@@ -299,9 +333,9 @@ def assess_incremental(evaluator: QualityEvaluator,
         def flush() -> None:
             if not batch:
                 return
-            t_eval = time.perf_counter()
-            outs = ev.eval_segment_batch([tt for _, tt in batch])
-            stats.chunk_eval_seconds.append(time.perf_counter() - t_eval)
+            with spans.span("scan.eval") as sp:
+                outs = ev.eval_segment_batch([tt for _, tt in batch])
+            stats.chunk_eval_seconds.append(sp.seconds)
             stats.attempts += len(batch)
             for (cid, _), (counts, regs) in zip(batch, outs):
                 on_chunk(cid, counts, regs)
@@ -332,12 +366,5 @@ def assess_incremental(evaluator: QualityEvaluator,
     stats.bytes_total = nbytes["total"]
     stats.bytes_rescanned = nbytes["rescanned"]
     stats.footprints_replayed = replayed[0]
-    stats.wall_seconds = time.perf_counter() - t0
     result.exec_stats = stats
-
-    store.commit(order)
-    if history:
-        from ..core import report
-        store.append_history(report.history_entry(
-            result, dataset_uri=dataset_uri), max_history=max_history)
-    return result
+    return result, store, order

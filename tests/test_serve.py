@@ -135,6 +135,36 @@ def test_upload_to_report_history_bit_identical_to_cold(server):
     assert "repro_bytes_rescanned_total" in text
 
 
+def test_metrics_export_span_seconds_and_queue_wait(server):
+    """A finished job adds its per-stage host seconds (self time of each
+    program span), the program's counters and its wait in the queue to
+    /metrics."""
+    data = bsbm_ntriples(200, seed=5)
+    job = wait_job(server, "obs", upload(server, "obs", data))
+    assert job["state"] == "done", job
+    st, prom = req(server, "GET", "/metrics")
+    assert st == 200
+    samples = {}
+    for line in prom.decode().splitlines():
+        if not line.startswith("#"):
+            key, _, value = line.rpartition(" ")
+            samples[key] = float(value)
+    spans = {k[len('repro_span_seconds_total{span="'):-2]: v
+             for k, v in samples.items()
+             if k.startswith("repro_span_seconds_total{")}
+    assert {"qa.run", "store.segment", "store.freeze", "ingest.tokenize",
+            "scan.wait", "scan.finalize"} <= set(spans)
+    assert all(v >= 0 for v in spans.values())
+    count = 'repro_program_count_total{counter="%s"}'
+    assert samples[count % "ingest.lines"] == len(data.splitlines())
+    assert samples[count % "ingest.bytes"] == len(data.encode())
+    assert 0 <= samples[count % "ingest.fallback_lines"] <= len(
+        data.splitlines())
+    assert samples[count % "transfer.bytes"] > 0
+    assert samples["repro_job_queue_wait_seconds_count"] == 1
+    assert samples["repro_job_queue_wait_seconds_sum"] >= 0
+
+
 def test_second_upload_rescans_only_changed_segments(server):
     data = bsbm_ntriples(100, seed=3)
     job1 = wait_job(server, "inc", upload(server, "inc", data))
