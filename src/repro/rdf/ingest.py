@@ -15,22 +15,23 @@ runs.  This module is the industrialized replacement:
 
 * **Reference fallback, not reference drift** — lines the structural fast
   path is not certain about (malformed syntax, escaped literals, exotic
-  whitespace, over-long tokens) are routed through the legacy parser, which
-  also owns the malformed-line-as-sentinel-triple semantics.  Whatever mix
-  of paths a block takes, the result is *byte-identical* to running the
-  legacy parser+encoder over the same text (the differential suite in
-  ``tests/test_ingest.py`` enforces this).
+  whitespace, tokens over ``MAX_FAST_TOKEN`` bytes) are routed through the
+  legacy parser, which also owns the malformed-line-as-sentinel-triple
+  semantics.  Whatever mix of paths a block takes, the result is
+  *byte-identical* to running the legacy parser+encoder over the same text
+  (the differential suite in ``tests/test_ingest.py`` enforces this).
 
 * **Batch dictionary encoding** — token byte-slices are gathered into
-  fixed-width matrices (two width tiers) and deduplicated with one
-  ``np.unique`` per tier over 64-bit row mixes, followed by an exact
-  byte-equality verification against each class representative (on the
-  astronomically rare mix collision the tier falls back to a full
-  byte-wise ``np.unique``).  Flag/length/datatype metadata is then computed
-  *once per unique term*: per-IRI work (syntactic validity, namespace
-  prefixes, known-predicate membership) is fully vectorized over the
-  unique-token matrix, and per-position planes are pure integer gathers
-  through ``TermDictionary.intern_keys_batch``.
+  fixed-width matrices (width tiers of 64 and 128 B, then doubling up to
+  the block's longest token, so a wide tier's padding stays under 2× its
+  bytes) and deduplicated with one ``np.unique`` per tier over 64-bit row
+  mixes, followed by an exact byte-equality verification against each
+  class representative (on the astronomically rare mix collision the tier
+  falls back to a full byte-wise ``np.unique``).  Flag/length/datatype
+  metadata is then computed *once per unique term*: per-IRI work
+  (syntactic validity, namespace prefixes, known-predicate membership) is
+  fully vectorized over the unique-token matrix, and per-position planes
+  are pure integer gathers through ``TermDictionary.intern_keys_batch``.
 
 * **Bounded-memory streaming** — ``stream_chunks`` reads a file in blocks,
   splits only on line boundaries (carrying partial-line remainders), and
@@ -55,10 +56,10 @@ from .encoder import TermDictionary
 from .parser import escape_literal, parse_ntriples
 from .triple_tensor import TripleTensor, N_PLANES, from_columns
 
-# Tokens longer than this take the reference path (keeps the dedup matrices
-# dense); covers every generator-produced IRI/literal with room to spare.
-MAX_FAST_TOKEN = 128
-_W1 = 64                # dense dedup tier; > _W1 uses the wide tier
+# Tokens longer than this take the reference path; long texts (reviews,
+# comments: ~2 KB) stay well under it.
+MAX_FAST_TOKEN = 8192
+_W1, _W2 = 64, 128      # narrow dedup tiers; wider ones double from _W2
 _MAX_LANG = 24          # fast-path cap on @lang suffix length
 _SKIP = 8               # max whitespace-run the vector sweeps resolve
 
@@ -294,10 +295,11 @@ def _fast_spans(scan: _Scan, lo: np.ndarray, hi: np.ndarray,
     return ok, spans
 
 
-# length-indexed tail masks: _TAIL_MASK[W][l] keeps the first l bytes of a row
+# length-indexed tail masks of the narrow tiers: _TAIL_MASK[W][l] keeps the
+# first l bytes of a row (a wide tier compares instead: the table is W²)
 _TAIL_MASK = {W: (np.arange(W)[None, :]
                   < np.arange(W + 1)[:, None]).astype(np.uint8)
-              for W in (_W1, MAX_FAST_TOKEN)}
+              for W in (_W1, _W2)}
 
 
 def _tier_dedup(pad: np.ndarray, ts: np.ndarray, lens: np.ndarray, W: int):
@@ -307,7 +309,10 @@ def _tier_dedup(pad: np.ndarray, ts: np.ndarray, lens: np.ndarray, W: int):
     byte-wise ``np.unique``).  Returns (umat, ulen, inv)."""
     win = np.lib.stride_tricks.sliding_window_view(pad, W)
     mat = win[ts]
-    mat *= _TAIL_MASK[W][lens]
+    if W in _TAIL_MASK:
+        mat *= _TAIL_MASK[W][lens]
+    else:
+        mat *= np.arange(W) < lens[:, None]
     u = mat.view(np.uint64)
     h = u[:, 0] * _FNV
     for j in range(1, W // 8):
@@ -325,21 +330,26 @@ def _tier_dedup(pad: np.ndarray, ts: np.ndarray, lens: np.ndarray, W: int):
 
 
 def _dedup_tokens(data: bytes, spans: np.ndarray):
-    """Batch dedup over token byte-slices in two width tiers.
+    """Batch dedup over token byte-slices in width tiers.
 
     Returns ``(tiers, inv)`` — ``tiers`` is a list of (umat, ulen) unique
     token matrices, ``inv`` maps each occurrence to its global class id
-    (tier-1 classes first).
+    (narrowest tier's classes first).
     """
     ts, te = spans[:, 0], spans[:, 1]
     lens = te - ts
-    pad = np.frombuffer(data + b"\0" * MAX_FAST_TOKEN, np.uint8)
-    small = lens <= _W1
+    # 64 and 128 B, then doubling until a tier holds the longest token: a
+    # wide tier's tokens are over half its width, so its matrix is under
+    # 2× their bytes
+    widths, longest = [_W1, _W2], lens.max()
+    while widths[-1] < longest:
+        widths.append(2 * widths[-1])
+    pad = np.frombuffer(data + b"\0" * widths[-1], np.uint8)
     inv = np.empty(ts.size, np.int32)
     tiers = []
     n_classes = 0
-    for W, rows in ((_W1, np.flatnonzero(small)),
-                    (MAX_FAST_TOKEN, np.flatnonzero(~small))):
+    for lo, W in zip([0] + widths, widths):
+        rows = np.flatnonzero((lens > lo) & (lens <= W))
         if rows.size == 0:
             continue
         umat, ulen, tinv = _tier_dedup(pad, ts[rows], lens[rows], W)
@@ -575,9 +585,12 @@ def _encode_block(data: bytes, dictionary: TermDictionary) -> np.ndarray:
     # reference path for everything the fast path is not sure about; owns
     # comment/blank re-splitting and the malformed-line sentinel semantics
     fb_rows = np.flatnonzero(~ok)
+    fast_spans = spans[ok].reshape(-1, 2)
     count("ingest.bytes", len(data))
     count("ingest.lines", L)
     count("ingest.fallback_lines", fb_rows.size)
+    count("ingest.wide_tokens", np.count_nonzero(
+        fast_spans[:, 1] - fast_spans[:, 0] > _W2))
     fb_counts = np.zeros(fb_rows.size, np.int64)
     fb_terms = []
     with span("ingest.fallback"):
@@ -590,7 +603,6 @@ def _encode_block(data: bytes, dictionary: TermDictionary) -> np.ndarray:
                 fb_terms.append(o)
 
     # batch-dedup fast tokens → classes 0..U-1, with vectorized metadata
-    fast_spans = spans[ok].reshape(-1, 2)
     rekeyed = False
     if fast_spans.shape[0]:
         with span("ingest.dedup"):

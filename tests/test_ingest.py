@@ -13,7 +13,7 @@ import os
 import numpy as np
 import pytest
 
-from repro import qa
+from repro import qa, spans
 from repro.rdf import (DirtProfile, TermDictionary, bsbm_ntriples, encode,
                        parse_encode, parse_ntriples, stream_chunks,
                        stream_chunks_text, vocab)
@@ -273,12 +273,112 @@ def test_vectorized_iri_validity_matches_regex():
     assert got == want
 
 
-def test_long_tokens_take_fallback_and_match():
+# --- tokens over 128 B: the wide dedup tiers -----------------------------------
+
+XSD = vocab.XSD_NS
+CAP = ingest.MAX_FAST_TOKEN
+NARROW = 128             # the widest of the two narrow dedup tiers
+LONG_LENGTHS = (128, 129, 255, 256, 257, 2200, CAP, CAP + 1)
+
+
+def _fill(unit, nbytes):
+    """``unit`` repeated, then ASCII padding, to exactly ``nbytes`` bytes."""
+    s = unit * (nbytes // len(unit.encode()))
+    return s + "a" * (nbytes - len(s.encode()))
+
+
+def _iri(n):
+    return "<http://example.org/" + _fill("x", n - 21) + ">"
+
+
+def _lit(n, unit="y", suffix=""):
+    return '"' + _fill(unit, n - 2 - len(suffix)) + '"' + suffix
+
+
+# each shape puts one token of exactly n bytes into a line
+LONG_SHAPES = {
+    "plain": lambda n: f"<http://s> <http://p> {_lit(n)} .",
+    "lang": lambda n: f"<http://s> <http://p> {_lit(n, 'w ', '@en')} .",
+    "xsd_string": lambda n: (
+        f"<http://s> <http://p> {_lit(n, 'v ', f'^^<{XSD}string>')} ."),
+    "xsd_integer": lambda n: (
+        f"<http://s> <http://p> {_lit(n, '7', f'^^<{XSD}integer>')} ."),
+    "iri_subject": lambda n: f"{_iri(n)} <http://p> <http://o> .",
+    "iri_object": lambda n: f"<http://s> <http://p> {_iri(n)} .",
+    "blank": lambda n: f"_:{_fill('b', n - 2)} <http://p> <http://o> .",
+    "raw_tab": lambda n: "<http://s> <http://p> " + _lit(n, "t\t") + " .",
+    "non_ascii": lambda n: f"<http://s> <http://p> {_lit(n, 'é中')} .",
+    "license": lambda n: (
+        f'<http://s> <http://p> "Creative Commons {_fill("z", n - 19)}" .'),
+}
+
+
+def _long_case(name):
+    """(text, token occurrences over 128 B and within the cap, lines
+    holding a token over the cap) of one case."""
+    if name in LONG_SHAPES:
+        text = "".join(LONG_SHAPES[name](n) + "\n" for n in LONG_LENGTHS)
+        wide = sum(NARROW < n <= CAP for n in LONG_LENGTHS)
+        return text + "<http://s> <http://p> <http://o> .\n", wide, 1
+    if name == "repeated":
+        # the same long tokens within a block and, streamed, across blocks
+        lit, iri = _lit(2200, "r "), _iri(300)
+        lines = [f"{iri} <http://p> {lit} .",
+                 "<http://s> <http://p> <http://o> .",
+                 f"<http://s> <http://p> {lit} .",
+                 f"<http://s> <http://p> {iri} .",
+                 f"{iri} <http://p> {lit} ."]
+        return "".join(ln + "\n" for ln in lines), 6, 0
+    # iri_and_literal: a 321 B IRI subject and a 502 B literal object
     long_iri = "http://example.org/" + "x" * 300
-    text = (f'<{long_iri}> <http://p> "{"y" * 500}" .\n'
-            '<http://s> <http://p> <http://o> .\n')
-    ref, vec = assert_identical(text)
-    assert len(ref) == 2
+    return (f'<{long_iri}> <http://p> "{"y" * 500}" .\n'
+            '<http://s> <http://p> <http://o> .\n'), 2, 0
+
+
+@pytest.mark.parametrize("name", sorted(LONG_SHAPES) + ["repeated",
+                                                         "iri_and_literal"])
+def test_long_tokens_match_reference(name):
+    """Tokens up to the cap take the vectorized path in tiers wider than
+    128 B, one over it the reference parser; either way the planes and the
+    dictionary are the reference's, single-shot and streamed in blocks
+    smaller than a line."""
+    text, n_wide, n_fallback = _long_case(name)
+    with spans.run() as rec:
+        ref, _ = assert_identical(text)
+    assert rec.counts["ingest.wide_tokens"] == n_wide
+    assert rec.counts["ingest.fallback_lines"] == n_fallback
+    d_ref = TermDictionary()
+    encode(parse_ntriples(text), dictionary=d_ref)
+    d = TermDictionary()
+    chunks = list(stream_chunks_text(text, 3, dictionary=d, block_bytes=1024))
+    assert np.array_equal(np.concatenate([c.planes for c in chunks]),
+                          ref.planes)
+    assert d.terms == d_ref.terms
+    assert np.array_equal(d.flags, d_ref.flags)
+
+
+def test_wide_tiers_exist_only_for_long_tokens(monkeypatch):
+    """A block with no token over 128 B dedups in the 64 and 128 B tiers
+    alone; longer tokens add doubling tiers, each under 2x its bytes."""
+    seen = []
+    real = ingest._tier_dedup
+
+    def recording(pad, ts, lens, W):
+        seen.append((W, lens.size * W, int(lens.sum())))
+        return real(pad, ts, lens, W)
+
+    monkeypatch.setattr(ingest, "_tier_dedup", recording)
+    narrow = ('<http://s> <http://p> "x" .\n'
+              f'<http://s> <http://p> {_lit(128)} .\n')
+    assert_identical(narrow)
+    assert [w for w, _, _ in seen] == [64, 128]
+    seen.clear()
+    wide = "".join(f"{_iri(n)} <http://p> {_lit(n)} .\n"
+                   for n in (129, 300, 2200, 2200))
+    assert_identical(wide)
+    assert [w for w, _, _ in seen] == [64, 256, 512, 4096]
+    for W, mat_bytes, token_bytes in seen[1:]:
+        assert mat_bytes < 2 * token_bytes
 
 
 def test_parse_encode_accepts_bytes_and_str():

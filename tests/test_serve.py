@@ -139,7 +139,9 @@ def test_metrics_export_span_seconds_and_queue_wait(server):
     """A finished job adds its per-stage host seconds (self time of each
     program span), the program's counters and its wait in the queue to
     /metrics."""
-    data = bsbm_ntriples(200, seed=5)
+    # one 300 B literal, deduplicated in a wide tier
+    data = bsbm_ntriples(200, seed=5) + (
+        f'<http://s> <http://p> "{"w" * 298}" .\n')
     job = wait_job(server, "obs", upload(server, "obs", data))
     assert job["state"] == "done", job
     st, prom = req(server, "GET", "/metrics")
@@ -160,6 +162,7 @@ def test_metrics_export_span_seconds_and_queue_wait(server):
     assert samples[count % "ingest.bytes"] == len(data.encode())
     assert 0 <= samples[count % "ingest.fallback_lines"] <= len(
         data.splitlines())
+    assert samples[count % "ingest.wide_tokens"] == 1
     assert samples[count % "transfer.bytes"] > 0
     assert samples["repro_job_queue_wait_seconds_count"] == 1
     assert samples["repro_job_queue_wait_seconds_sum"] >= 0

@@ -216,6 +216,27 @@ def test_fallback_lines_count_the_reference_parser_calls(monkeypatch):
         "ingest.lines"]
 
 
+def test_wide_tokens_count_long_tokens_off_the_reference_parser(monkeypatch):
+    def refuse(text):
+        raise AssertionError(f"reference parser called on {text[:40]!r}")
+
+    monkeypatch.setattr(ingest, "parse_ntriples", refuse)
+    # tokens of 129 B to 2 KB, some repeated, among short ones
+    tokens = [f'"{"r" * (n - 2)}"' for n in range(129, 2049, 101)]
+    tokens += [f"<http://example.org/{'i' * (n - 21)}>" for n in (129, 700)]
+    lines = [f"<http://s> <http://p> {t} .\n" for t in tokens + tokens[:3]]
+    lines += ["<http://s> <http://p> <http://o> .\n",
+              f"{tokens[-1]} <http://p> {tokens[-2]} .\n"]
+    data = "".join(lines).encode()
+    with spans.run() as rec:
+        ingest.parse_encode(data)
+    long_occurrences = sum(len(t) > 128 for ln in lines
+                           for t in ln[:-3].split(" "))
+    assert long_occurrences == len(tokens) + 3 + 2
+    assert rec.counts["ingest.fallback_lines"] == 0
+    assert rec.counts["ingest.wide_tokens"] == long_occurrences
+
+
 def test_single_shot_results_carry_a_trace():
     text = bsbm_ntriples(400, seed=9)
     res = qa.assess(text, metrics="paper")
