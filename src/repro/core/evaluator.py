@@ -19,7 +19,13 @@ Execution modes:
   then counter vectors ``psum`` and register banks ``pmax`` across every
   axis.  ``device_planes`` pads rows up to a device multiple first —
   padding rows carry zero flag planes, so an uneven final shard is
-  invisible to counters and sketches alike.  ``eval_segment_batch``
+  invisible to counters and sketches alike.  A device walks more rows
+  than ``SCAN_BLOCK_ROWS`` in blocks of that many (``lax.fori_loop``
+  carrying the counters and register banks), so a pass's temporaries are
+  those of one block however much a chip holds; the collectives run once,
+  after the loop.  A ``TripleTensor`` whose planes are already a
+  ``jax.Array`` placed with the pass's row sharding (a resident dataset)
+  is scanned where it lies.  ``eval_segment_batch``
   additionally distributes *whole segments* (one independent dataset
   slice per device slot — the embarrassingly-parallel axis incremental
   rescans use, where per-segment results must come back unreduced).
@@ -52,6 +58,60 @@ from .metrics import ALL_METRICS, Metric, get_metrics
 from .planner import Plan, plan, plan_single
 
 BACKENDS = ("jnp", "pallas", "fused_scan")
+
+# Rows one device scans at a time.  A pass over more rows walks them in
+# blocks of this many, so that its temporaries stay those of one block
+# (under a MB on a TPU v5e) however much a chip holds; over a whole shard
+# at once the HLL scatter's bucket and rank arrays take ~29 B a row.  A
+# shard of at most one block is scanned without a loop.
+SCAN_BLOCK_ROWS = 1 << 21
+
+
+def scan_blocks(rows: int) -> int:
+    """Row blocks a pass walks over one device's ``rows``."""
+    return max(1, -(-rows // SCAN_BLOCK_ROWS))
+
+
+_hll_estimate = jax.jit(hll.hll_estimate)
+
+
+def _merge_pass(acc, out):
+    """Counters summed, register banks max-merged: one block's result
+    folded into the running one."""
+    return (acc[0] + out[0],
+            {k: jnp.maximum(acc[1][k], out[1][k]) for k in acc[1]})
+
+
+def _row_blocked(block_pass):
+    """``block_pass`` (rows -> counters, registers) over a device's rows
+    in ``SCAN_BLOCK_ROWS`` blocks; a tail shorter than a block is one step
+    more.  At most one block is ``block_pass`` itself, with no loop.
+
+    ``block_pass`` is traced for the loop's body, once more for a tail,
+    and once for the shapes of the running result; its ``record_scan``
+    calls count the body alone, since together they read the rows once."""
+
+    def local_pass(planes):
+        block = SCAN_BLOCK_ROWS
+        if planes.shape[0] <= block:
+            return block_pass(planes)
+        n_full, tail = divmod(planes.shape[0], block)
+        with count_scans():
+            shapes = jax.eval_shape(block_pass, jax.ShapeDtypeStruct(
+                (block, planes.shape[1]), planes.dtype))
+        zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+
+        def body(i, acc):
+            rows = jax.lax.dynamic_slice_in_dim(planes, i * block, block)
+            return _merge_pass(acc, block_pass(rows))
+
+        acc = jax.lax.fori_loop(0, n_full, body, zeros)
+        if tail:
+            with count_scans():
+                acc = _merge_pass(acc, block_pass(planes[n_full * block:]))
+        return acc
+
+    return local_pass
 
 
 @dataclasses.dataclass
@@ -103,7 +163,8 @@ class QualityEvaluator:
 
     # -- single-pass core (one plan) ------------------------------------------
     def _local_pass_fn(self, pln: Plan):
-        """The un-jitted single-device pass planes -> (counts, sketches).
+        """The un-jitted single-device pass planes -> (counts, sketches),
+        walking the rows in ``SCAN_BLOCK_ROWS`` blocks.
 
         Each branch declares its HBM data passes via ``record_scan`` (op
         wrappers do it for the kernel paths), so tracing this function under
@@ -114,7 +175,7 @@ class QualityEvaluator:
         sketch_specs = pln.sketch_specs
         backend, hll_p = self.backend, self.hll_p
 
-        def local_pass(planes):
+        def block_pass(planes):
             if backend == "fused_scan":
                 from ..kernels.fused_scan import ops as fops
                 counts, regs = fops.fused_scan(
@@ -139,7 +200,7 @@ class QualityEvaluator:
                             hll.hll_init(hll_p), planes, cols, valid=valid)
             return counts, regs
 
-        return local_pass
+        return _row_blocked(block_pass)
 
     def _pass_fn(self, pln: Plan):
         """Build the jitted (and mesh-mapped) pass function for one plan."""
@@ -150,7 +211,9 @@ class QualityEvaluator:
         mesh = self.mesh
         axes = tuple(mesh.axis_names)
 
-        def dist_pass(planes):
+        # its executable is ``jit_mesh_pass``: the name the device trace
+        # finds the row-sharded pass by
+        def mesh_pass(planes):
             counts, regs = local_pass(planes)
             for ax in axes:
                 counts = jax.lax.psum(counts, ax)
@@ -159,7 +222,7 @@ class QualityEvaluator:
 
         shard_rows = P(axes)  # rows split over every axis (pure DP)
         mapped = jax.shard_map(
-            dist_pass, mesh=mesh,
+            mesh_pass, mesh=mesh,
             in_specs=(shard_rows,),
             out_specs=(P(), {s: P() for s, _ in pln.sketch_specs}),
             check_vma=False,  # pallas_call outputs carry no vma info
@@ -213,7 +276,19 @@ class QualityEvaluator:
             return None
         return NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
 
+    def _placed(self, arr: jax.Array) -> bool:
+        """Whether ``arr`` lies where the pass reads it: a row count the
+        pass can split, under the pass's row sharding (on one device
+        without a mesh)."""
+        if arr.shape[0] % max(1, self._row_multiple()):
+            return False
+        if self.mesh is None:
+            return len(arr.sharding.device_set) == 1
+        return arr.sharding.is_equivalent_to(self._sharding(), arr.ndim)
+
     def device_planes(self, tensor: TripleTensor):
+        if isinstance(tensor.planes, jax.Array):
+            return self._resident_planes(tensor)
         # host NumPy goes straight to its shards: no staging copy of the
         # whole chunk on one device.  The span ends when the copy has
         # landed, so it times the transfer and not its enqueue.
@@ -222,6 +297,20 @@ class QualityEvaluator:
             arr = jax.device_put(padded.planes, self._sharding())
             arr.block_until_ready()
         spans.count("transfer.bytes", padded.planes.nbytes)
+        return arr
+
+    def _resident_planes(self, tensor: TripleTensor):
+        """Planes already on the device, scanned where they lie: padded
+        on the device and resharded device to device only where they do
+        not lie as the pass reads them.  Nothing crosses from the host."""
+        with spans.span("scan.place"):
+            arr = tensor.planes
+            if not self._placed(arr):
+                arr = tensor.padded_to(max(1, self._row_multiple())).planes
+                arr = jax.device_put(
+                    arr, self._sharding() or jax.local_devices()[0])
+                arr.block_until_ready()
+        spans.count("resident.bytes", arr.nbytes)
         return arr
 
     # -- public API ------------------------------------------------------------
@@ -261,6 +350,8 @@ class QualityEvaluator:
         """Launch every plan's pass over device-resident ``arr`` WITHOUT
         blocking (JAX dispatch is async) — the device-side half of
         ``eval_chunk``.  Pair with ``materialize_chunk``."""
+        spans.count("scan.blocks", len(self._pass_fns) * scan_blocks(
+            arr.shape[0] // self._shard_count()))
         with spans.span("scan.dispatch"):
             return [fn(arr) for fn in self._pass_fns]
 
@@ -371,7 +462,9 @@ class QualityEvaluator:
 
     def finalize_state(self, state: dict, n_triples: int) -> AssessmentResult:
         with spans.span("scan.finalize"):
-            est = {"sketch:" + k: float(hll.hll_estimate(jnp.asarray(v)))
+            # the banks go up by an explicit copy and the estimate runs
+            # as one program: no implicit host-to-device transfer
+            est = {"sketch:" + k: float(_hll_estimate(jax.device_put(v)))
                    for k, v in state["sketches"].items()}
             values: dict[str, float] = {}
             counts_out: dict[str, dict[str, int]] = {}

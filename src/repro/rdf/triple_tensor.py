@@ -9,8 +9,11 @@ with pure integer ops — the TPU hot path never sees a string.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from . import vocab
@@ -51,7 +54,9 @@ class TripleTensor:
 
     ``planes``: (N, N_PLANES) int32 — may include padding rows, which have all
     flag planes 0 (in particular the VALID bit unset, so they are invisible to
-    every metric, including ``count(triples)``).
+    every metric, including ``count(triples)``).  Host NumPy, or a
+    ``jax.Array`` already on the devices (a resident dataset: the evaluator
+    scans it where it lies).
     ``n_valid``: number of real triples (≤ N).
     """
 
@@ -72,14 +77,18 @@ class TripleTensor:
         return self.planes.shape[0]
 
     def padded_to(self, multiple: int) -> "TripleTensor":
-        """Pad row count up to a multiple (for sharding); pads are invisible."""
+        """Pad row count up to a multiple (for sharding); pads are invisible.
+        Planes on the devices are padded there, under their sharding."""
         n = self.planes.shape[0]
         target = ((n + multiple - 1) // multiple) * multiple
         if target == n:
             return self
-        pad = np.zeros((target - n, N_PLANES), dtype=np.int32)
-        return TripleTensor(np.concatenate([self.planes, pad], axis=0),
-                            self.n_valid, self.n_terms)
+        if isinstance(self.planes, np.ndarray):
+            pad = np.zeros((target - n, N_PLANES), dtype=np.int32)
+            planes = np.concatenate([self.planes, pad], axis=0)
+        else:
+            planes = _pad_rows(self.planes, target - n)
+        return TripleTensor(planes, self.n_valid, self.n_terms)
 
     def take(self, n: int) -> "TripleTensor":
         return TripleTensor(self.planes[:n], min(self.n_valid, n), self.n_terms)
@@ -104,6 +113,13 @@ class TripleTensor:
             out.append(TripleTensor(block, nv, self.n_terms))
             remaining -= rows
         return out
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_rows(planes, rows: int):
+    """``rows`` zero rows after device-resident ``planes``: one program,
+    whose zeros are made on the device and not copied from the host."""
+    return jnp.pad(planes, ((0, rows), (0, 0)))
 
 
 def mix32(x: np.ndarray) -> np.ndarray:
