@@ -234,6 +234,16 @@ class QualityEvaluator:
         return [self._pass_fn(p) for p in self.plans]
 
     @functools.cached_property
+    def _count_form_sketches(self) -> int:
+        """Sketches a pass over every plan folds by the count form: the
+        ``jnp`` backend's, at a p that ``hll.hll_update`` folds so.  Times
+        the rows a pass reads, it is the counter
+        ``scan.sketch_count_rows``, recorded where it is not 0."""
+        if self.backend != "jnp" or not hll.uses_count_form(self.hll_p):
+            return 0
+        return sum(len(pln.sketch_specs) for pln in self.plans)
+
+    @functools.cached_property
     def passes_per_chunk(self) -> int:
         """ACTUAL HBM data passes one chunk evaluation performs, measured
         by tracing every plan's pass function under the scan counter — 1
@@ -352,6 +362,9 @@ class QualityEvaluator:
         ``eval_chunk``.  Pair with ``materialize_chunk``."""
         spans.count("scan.blocks", len(self._pass_fns) * scan_blocks(
             arr.shape[0] // self._shard_count()))
+        if self._count_form_sketches:
+            spans.count("scan.sketch_count_rows",
+                        self._count_form_sketches * arr.shape[0])
         with spans.span("scan.dispatch"):
             return [fn(arr) for fn in self._pass_fns]
 
@@ -433,6 +446,9 @@ class QualityEvaluator:
             arr = jax.device_put(stack, self._sharding())
             arr.block_until_ready()
         spans.count("transfer.bytes", stack.nbytes)
+        if self._count_form_sketches:
+            spans.count("scan.sketch_count_rows",
+                        self._count_form_sketches * stack.shape[0] * rows)
         with spans.span("scan.dispatch"):
             dispatched = [fn(arr) for fn in self._batch_pass_fns]
         # each batched output comes to the host once and is split there:

@@ -1,8 +1,8 @@
 """jnp reference path for the fused scan — the same one-logical-pass
 contract (counts + every sketch register bank from one planes argument),
 built from the independently-tested reference pieces: the bytecode
-interpreter (``core.expr.eval_program_jnp``) and the scatter-max sketch
-update (``core.sketches.hll_update``).  Bit-identical to the megakernel;
+interpreter (``core.expr.eval_program_jnp``) and the sketch update
+(``core.sketches.hll_update``).  Bit-identical to the megakernel;
 ``tests/test_kernels.py`` holds both to it."""
 from __future__ import annotations
 

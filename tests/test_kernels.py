@@ -139,6 +139,121 @@ def test_hll_block_n_bounded_by_p():
     np.testing.assert_array_equal(got, want)
 
 
+# --- HLL count form (core.sketches) -------------------------------------------
+
+def _unfmix32(x):
+    """The murmur3 finalizer run backwards."""
+    x = x.astype(np.uint32)
+    x ^= x >> np.uint32(16)
+    x = (x * np.uint32(pow(0xC2B2AE35, -1, 1 << 32))).astype(np.uint32)
+    x ^= (x >> np.uint32(13)) ^ (x >> np.uint32(26))
+    x = (x * np.uint32(pow(0x85EBCA6B, -1, 1 << 32))).astype(np.uint32)
+    x ^= x >> np.uint32(16)
+    return x
+
+
+def _planes_hashing_to(hashes):
+    """Valid rows whose ``hash_columns(planes, (COL_S,))`` is ``hashes``."""
+    h = _unfmix32(np.asarray(hashes, np.uint32))
+    h = ((h - np.uint32(0xE6546B64))
+         * np.uint32(pow(5, -1, 1 << 32))).astype(np.uint32)
+    planes = np.zeros((len(h), N_PLANES), np.int32)
+    planes[:, COL_S] = (_unfmix32(h) ^ np.uint32(0x9E3779B9)).view(np.int32)
+    planes[:, COL_S_FLAGS] = 1
+    return planes
+
+
+def _count_form_case(case, n, p):
+    """(planes, cols, valid) for one exactness case."""
+    rng = np.random.default_rng(n * 31 + p)
+    if case in ("random", "unmasked"):
+        planes = rng.integers(-2**31, 2**31, (n, N_PLANES),
+                              dtype=np.int64).astype(np.int32)
+        valid = None if case == "unmasked" else rng.random(n) < 0.8
+        return planes, (COL_S, COL_P, COL_O), valid
+    if case == "all_invalid":
+        planes = rng.integers(-2**31, 2**31, (n, N_PLANES),
+                              dtype=np.int64).astype(np.int32)
+        return planes, (COL_S, COL_P, COL_O), np.zeros(n, bool)
+    # hashes placed in the first and last bucket: at the rank cap
+    # (w == 0) or at a random rank, among random rows
+    top = np.uint32(32 - p)
+    edge = np.array([0, (1 << p) - 1], np.uint32)[rng.integers(0, 2, n)]
+    low = rng.integers(0, 1 << (32 - p), n, dtype=np.uint64).astype(np.uint32)
+    if case == "rank_cap":
+        low[: n // 2] = 0
+        edge[n // 4: n // 2] = rng.integers(0, 1 << p, n // 2 - n // 4)
+    hashes = (edge << top) | low
+    hashes[-n // 4:] = rng.integers(0, 1 << 32, n // 4, dtype=np.uint64)
+    planes = _planes_hashing_to(hashes)
+    np.testing.assert_array_equal(href.hash_columns_np(planes, (COL_S,)),
+                                  hashes)
+    return planes, (COL_S,), rng.random(n) < 0.9
+
+
+COUNT_FORM_CASES = ([("random", n) for n in (1, 8, 1000, (1 << 16) + 5)]
+                    + [("unmasked", 1000), ("all_invalid", 1000),
+                       ("rank_cap", 1000), ("edge_buckets", 1000)])
+
+
+@pytest.mark.parametrize("case,n", COUNT_FORM_CASES)
+@pytest.mark.parametrize("p", [4, 8, 12, hll.COUNT_FORM_MAX_P])
+def test_hll_count_form_equals_scatter_max(case, n, p):
+    """The count form's registers ≡ the scatter-max's, bit for bit, in
+    the CPU's steps of rows (more than one at 2^16 + 5 rows)."""
+    assert hll.uses_count_form(p)
+    planes, cols, valid = _count_form_case(case, n, p)
+    v = None if valid is None else jnp.asarray(valid)
+    start = jnp.asarray(np.random.default_rng(p).integers(
+        0, 3, 1 << p, dtype=np.int32))
+    got = np.asarray(hll.hll_update(start, jnp.asarray(planes), cols, v))
+    want = np.asarray(hll.hll_update_scatter(start, jnp.asarray(planes),
+                                             cols, v))
+    np.testing.assert_array_equal(got, want)
+    oracle = np.maximum(np.asarray(start),
+                        href.hll_fold_ref(planes, cols, p, valid=valid))
+    np.testing.assert_array_equal(got, oracle)
+    if case == "rank_cap":
+        assert got.max() == 33 - p
+    if case in ("rank_cap", "edge_buckets"):
+        assert got[0] > 0 and got[-1] > 0
+
+
+@pytest.mark.parametrize("p", [4, 8, 12, hll.COUNT_FORM_MAX_P])
+def test_hll_count_form_in_one_dot_equals_scatter_max(monkeypatch, p):
+    """Where the one-hots fuse into the dot (the TPU), every row is
+    folded in one dot, with a tail of none: the same registers."""
+    monkeypatch.setattr(hll, "fuses_one_hots", lambda: True)
+    for case in ("random", "rank_cap"):
+        planes, cols, valid = _count_form_case(case, 1000, p)
+        got = hll.hll_update(hll.hll_init(p), jnp.asarray(planes), cols,
+                             jnp.asarray(valid))
+        want = hll.hll_update_scatter(hll.hll_init(p), jnp.asarray(planes),
+                                      cols, jnp.asarray(valid))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("p", [12, hll.COUNT_FORM_MAX_P,
+                               hll.COUNT_FORM_MAX_P + 1])
+def test_hll_update_takes_the_scatter_only_above_the_cut(p):
+    """Up to ``COUNT_FORM_MAX_P`` no scatter is lowered and the pass
+    counts its sketches' rows in ``scan.sketch_count_rows``; above it the
+    scatter folds them and the counter stays unrecorded."""
+    import jax
+    from repro import spans
+    from repro.core import QualityEvaluator
+    shape = jax.ShapeDtypeStruct((1000, N_PLANES), jnp.int32)
+    text = jax.jit(lambda a: hll.hll_update(
+        hll.hll_init(p), a, (COL_S,))).lower(shape).as_text()
+    assert ("stablehlo.scatter" in text) == (p > hll.COUNT_FORM_MAX_P)
+    tt = synth_encoded(1000, seed=p)
+    ev = QualityEvaluator(ALL_METRICS, hll_p=p)
+    with spans.run() as rec:
+        ev.eval_chunk(tt)
+    want = 0 if p > hll.COUNT_FORM_MAX_P else 2 * tt.planes.shape[0]
+    assert rec.counts.get("scan.sketch_count_rows", 0) == want
+
+
 # --- fused counts+sketches megakernel ------------------------------------------
 
 @pytest.mark.parametrize("n", [1, 8, 100, 8193, 20000])

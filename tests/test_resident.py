@@ -62,6 +62,7 @@ print(json.dumps({{
     rows = -(-n // 4) * 4
     assert out["counters"]["resident.bytes"] == rows * 13 * 4
     assert out["counters"]["scan.blocks"] == -(-(rows // 4) // 1024)
+    assert out["counters"]["scan.sketch_count_rows"] == 2 * rows
     assert "transfer.bytes" not in out["counters"]
     assert "scan.place" in out["spans"]
     assert "scan.transfer" not in out["spans"]
@@ -133,5 +134,6 @@ def test_single_device_resident_run_records_no_transfer():
                                      tt.n_terms))
     assert res.values == host.values
     assert rec.counts == {"resident.bytes": tt.planes.nbytes,
-                          "scan.blocks": 1}
+                          "scan.blocks": 1,
+                          "scan.sketch_count_rows": tt.planes.shape[0]}
     assert "scan.place" in {s.name for s in rec.spans}

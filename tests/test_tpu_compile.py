@@ -19,6 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import QualityEvaluator
+from repro.core import sketches as hll
 from repro.core.metrics import ALL_METRICS, get_metrics
 from repro.core.planner import plan
 from repro.kernels import SCOPED_VMEM_BYTES
@@ -114,12 +115,28 @@ def test_row_sharded_fused_scan_compiles_on_2x2(topo, monkeypatch):
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < HBM_BYTES
 
 
-def test_row_blocked_jnp_pass_holds_bsbm_200gb_on_2x2(topo):
+def test_jnp_pass_folds_sketches_without_a_scatter(one_chip, monkeypatch):
+    """The ``jnp`` ``all`` pass over (2^20, 13) planes on one chip folds
+    both HLL sketches by the count form: no scatter in its program, and
+    the one-hots fused into the dots, so the temporaries stay at 1.16 MB
+    as with the scatter.  ``jax.default_backend`` is the CPU here, so the
+    test steers the platform's one-hot choice."""
+    monkeypatch.setattr(hll, "fuses_one_hots", lambda: True)
+    ev = QualityEvaluator(ALL_METRICS, backend="jnp")
+    x = jax.ShapeDtypeStruct((ROWS, N_PLANES), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(ev._local_pass_fn(ev.plans[0])).lower(x).compile()
+    assert " scatter(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+def test_row_blocked_jnp_pass_holds_bsbm_200gb_on_2x2(topo, monkeypatch):
     """The ``jnp`` mesh pass over 3 x 2^28 rows, the resident four-chip
     BSBM cell's dataset: 12.88 GB of planes a chip.  The pass walks each
     shard in row blocks, so its temporaries stay those of one block and
     argument plus temporaries fit the 15.75 GB a program may use; a pass
-    over the whole shard at once needs 17.44 GB."""
+    over the whole shard at once needs 17.44 GB.  The sketches' one-hots
+    fuse into their dots as on the chip."""
+    monkeypatch.setattr(hll, "fuses_one_hots", lambda: True)
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))
     ev = QualityEvaluator(ALL_METRICS, backend="jnp", mesh=mesh)
     x = jax.ShapeDtypeStruct((3 << 28, N_PLANES), jnp.int32,
