@@ -2,8 +2,7 @@
 
   PYTHONPATH=src python -m benchmarks.run [--quick]
 
-Emits results/*.json and a console summary. The roofline section reads the
-dry-run artifacts if present (results/dryrun.jsonl).
+Emits results/*.json and a console summary.
 """
 from __future__ import annotations
 
@@ -21,8 +20,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None,
-                    help="comma list: table4,fig2,fig3,fig4,roofline,"
-                         "ingest,scan")
+                    help="comma list: table4,fig2,fig3,fig4,ingest,scan")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
@@ -99,22 +97,6 @@ def main():
               f"{p['async_speedup_fused_scan']:.2f}x on streamed ingest "
               f"(identical={p['all_values_identical']}, "
               f"registers={p['hll_registers_bit_identical']})")
-
-    if only is None or "roofline" in only:
-        _section("Roofline — per (arch × shape) from the dry-run")
-        from . import roofline
-        p = roofline.run(quick=args.quick)
-        ok = [r for r in p["rows"] if "skip" not in r]
-        skips = [r for r in p["rows"] if "skip" in r]
-        if not p["rows"]:
-            print("  (no results/dryrun.jsonl yet — run "
-                  "PYTHONPATH=src python -m repro.launch.dryrun)")
-        for r in ok:
-            print(f"  {r['arch']:24s} {r['shape']:14s} dom={r['dominant']:10s} "
-                  f"bound={r['bound_s']:.2e}s mem={r['mem_per_device_gib']:6.2f}GiB "
-                  f"MFU-ceil={r['frac_compute']:.3f} floor={r['roofline_fraction']:.3f}")
-        for r in skips:
-            print(f"  {r['arch']:24s} {r['shape']:14s} SKIP ({r['skip'][:50]}…)")
 
     print(f"\nTotal benchmark time: {time.time() - t_start:.1f}s")
 

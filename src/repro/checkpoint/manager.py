@@ -2,13 +2,14 @@
 
 Design goals for 1000+-node deployments:
 * **device-independent state** — arrays are gathered to host numpy before
-  serialization, so a checkpoint written on a 512-chip mesh restores onto a
-  64-chip mesh (elastic restart); resharding happens at ``device_put`` time
-  from the target mesh's shardings.
+  serialization, so a checkpoint written under one mesh restores onto a mesh
+  of another shape (elastic restart; ``tests/test_multidevice.py`` writes
+  under a (4, 2) mesh and restores onto a (2, 4) one); resharding happens
+  at ``device_put`` time from the target mesh's shardings.
 * **atomic** — writes go to ``<dir>/.tmp.<step>`` then ``os.replace`` into
   place; a crash mid-write never corrupts the latest checkpoint.
 * **async** — ``save_async`` hands the serialized bytes to a writer thread so
-  the training/assessment loop is not blocked on disk.
+  the assessment loop is not blocked on disk.
 * **self-describing** — ``manifest.json`` records step, tree structure, and
   user metadata (mesh shape, config digest) for audit and compatibility
   checks on restore.

@@ -26,10 +26,6 @@ properties for the JAX engine:
   (``ChunkStats.devices`` records the shard count for provenance).
 * ``FaultInjector`` / ``WorkerFailure`` — deterministic failure injection
   (flaky workers, stragglers, coordinator crashes) for tests and drills.
-* ``compressed_psum`` — quantized cross-device mean-reduction with error
-  feedback, for bandwidth-bound reductions.
-* ``sharding`` — ``ShardingPolicy`` / ``split_params`` (logical parameter
-  axes → mesh shardings).
 
 Checkpoints are written through ``CheckpointManager.save_async``'s writer
 thread, so periodic checkpoints never stall the scan loop; ``run`` joins
@@ -45,13 +41,10 @@ import time
 import warnings
 from typing import Any, Callable, Iterable, Mapping, Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .. import spans
 from ..checkpoint import CheckpointManager
-from .sharding import ShardingPolicy, split_params
 
 
 class WorkerFailure(RuntimeError):
@@ -582,29 +575,6 @@ class ChunkScheduler:
         self._merge_and_checkpoint(state, cid, counts, regs, stats, faults)
 
 
-# --- compressed collectives ---------------------------------------------------
-
-def compressed_psum(x, axis_name: str, error, *, bits: int = 8):
-    """Quantized mean-``psum`` with error feedback.
-
-    Each shard adds its carried quantization ``error`` to ``x``, quantizes
-    to ``bits`` bits (symmetric, per-shard scale), reduces the decoded
-    values, and returns ``(mean, new_error)``. The residual is fed back on
-    the next call, so repeated reductions are unbiased (error-feedback SGD
-    compression); a one-off call is accurate to ~``2^-(bits-1)`` relative.
-    """
-    compensated = x + error
-    qmax = float((1 << (bits - 1)) - 1)
-    scale = jnp.max(jnp.abs(compensated)) / qmax
-    scale = jnp.maximum(scale, jnp.asarray(jnp.finfo(x.dtype).tiny, x.dtype))
-    q = jnp.clip(jnp.round(compensated / scale), -qmax, qmax)
-    decoded = (q * scale).astype(x.dtype)
-    new_error = compensated - decoded
-    n = jax.lax.psum(jnp.ones((), x.dtype), axis_name)
-    return jax.lax.psum(decoded, axis_name) / n, new_error
-
-
 __all__ = [
     "ChunkScheduler", "ChunkStats", "FaultInjector", "WorkerFailure",
-    "compressed_psum", "ShardingPolicy", "split_params",
 ]

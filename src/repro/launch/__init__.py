@@ -1,5 +1,6 @@
-"""Launchers: mesh construction, dry-run, training, serving, assessment,
-and the assessment-as-a-service daemon (``qa_serve``)."""
+"""Launchers: mesh construction, the assessment CLI (``assess``), the
+catalog assessor (``qa_catalog``) and the assessment-as-a-service daemon
+(``qa_serve``)."""
 from __future__ import annotations
 
 import os

@@ -1,20 +1,11 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION (not a module-level constant) so importing this module never
-touches jax device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* any jax
-init, and smoke tests must keep seeing 1 device.
+Functions, not module-level constants, so importing this module never
+touches jax device state.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 (256 chips / pod) single-pod, or 2×16×16 (512 chips) multi-pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -34,8 +25,3 @@ def make_assessment_mesh(devices: int = 0):
     if not 1 <= n <= avail:
         raise ValueError(f"devices must be in [1, {avail}], got {n}")
     return jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
-
-
-def data_axes(mesh) -> tuple[str, ...]:
-    """Axes used for batch/data parallelism (everything except 'model')."""
-    return tuple(a for a in mesh.axis_names if a != "model")

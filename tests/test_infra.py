@@ -1,17 +1,12 @@
-"""Infrastructure: checkpointing, sharding policy, scheduler, DIN, configs."""
-import dataclasses
+"""Infrastructure: checkpointing."""
 import os
 import tempfile
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
-from repro.dist.sharding import ShardingPolicy, split_params
-from repro.models import din as DIN
 
 
 # --- checkpoint ---------------------------------------------------------------
@@ -131,86 +126,3 @@ def test_checkpoint_missing_key_raises():
         mgr.save(1, {"a": np.ones(3)})
         with pytest.raises(KeyError):
             mgr.restore(1, {"a": np.zeros(3), "b": np.zeros(2)})
-
-
-# --- sharding policy ------------------------------------------------------------
-
-def test_policy_tp_and_fsdp():
-    pol = ShardingPolicy(mesh_axes=("pod", "data", "model"), fsdp=True)
-    # TP dim → model; embed dim → data (fsdp)
-    assert pol.spec_for(("embed", "q_heads", None)) == P("data", "model")
-    assert pol.spec_for((None, "embed", "mlp")) == P(None, "data", "model")
-    assert pol.spec_for(("batch", None)) == P(("pod", "data"))
-    pol_all = ShardingPolicy(mesh_axes=("pod", "data", "model"),
-                             batch_over_all=True)
-    assert pol_all.spec_for(("batch",)) == P(("pod", "data", "model"))
-
-
-def test_policy_divisibility_fallback():
-    pol = ShardingPolicy(mesh_axes=("data", "model"), fsdp=True)
-    sizes = {"data": 16, "model": 16}
-    # 40 heads don't divide 16 → replicated
-    assert pol.spec_for(("embed", "q_heads", None), (5120, 40, 128),
-                        sizes) == P("data")
-    # 48 heads do
-    assert pol.spec_for(("embed", "q_heads", None), (6144, 48, 128),
-                        sizes) == P("data", "model")
-
-
-def test_split_params_nested():
-    tree = {"mlp": [((np.ones((4, 8)), (None, "mlp")),
-                     (np.zeros((8,)), ("mlp",)))],
-            "w": (np.ones((3, 3)), ("embed", None))}
-    params, logical = split_params(tree)
-    assert params["w"].shape == (3, 3)
-    assert logical["w"] == ("embed", None)
-    assert params["mlp"][0][0].shape == (4, 8)
-    assert logical["mlp"][0][1] == ("mlp",)
-
-
-# --- DIN -----------------------------------------------------------------------
-
-def test_din_attention_mask_zeroes_history():
-    cfg = dataclasses.replace(DIN.DINConfig(), n_items=100, n_cats=10)
-    params, _ = DIN.init_din(cfg, jax.random.key(0))
-    rng = np.random.default_rng(0)
-    b = DIN.synth_batch(cfg, 4, 1, rng,
-                        reduced={"n_items": 100, "n_cats": 10})
-    out1 = DIN.forward(cfg, params, b)
-    # changing FULLY-MASKED history slots must not change the output
-    b2 = dict(b)
-    hist = b["hist_items"].copy()
-    masked = b["hist_mask"] == 0
-    hist[masked] = (hist[masked] + 7) % 100
-    b2["hist_items"] = hist
-    out2 = DIN.forward(cfg, params, b2)
-    np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
-
-
-def test_din_retrieval_equals_per_candidate():
-    cfg = dataclasses.replace(DIN.DINConfig(), n_items=200, n_cats=8)
-    params, _ = DIN.init_din(cfg, jax.random.key(1))
-    rng = np.random.default_rng(2)
-    b = DIN.synth_batch(cfg, 1, 16, rng,
-                        reduced={"n_items": 200, "n_cats": 8})
-    batched = np.asarray(DIN.forward(cfg, params, b))  # (1, 16)
-    for c in range(0, 16, 5):
-        single = {**b, "cand_item": b["cand_item"][:, c:c + 1],
-                  "cand_cat": b["cand_cat"][:, c:c + 1],
-                  "labels": b["labels"][:, c:c + 1]}
-        one = np.asarray(DIN.forward(cfg, params, single))
-        np.testing.assert_allclose(batched[0, c], one[0, 0], atol=1e-5)
-
-
-# --- config registry -----------------------------------------------------------
-
-def test_all_archs_registered():
-    from repro.configs import REGISTRY
-    expected = {"qwen2.5-14b", "internlm2-20b", "gemma3-12b",
-                "deepseek-v2-236b", "granite-moe-1b-a400m", "gatedgcn",
-                "dimenet", "equiformer-v2", "graphcast", "din",
-                "dist-quality-assessment"}
-    assert expected <= set(REGISTRY)
-    for name in expected:
-        spec = REGISTRY[name]
-        assert len(spec.shape_names) >= 4

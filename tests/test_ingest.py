@@ -108,6 +108,53 @@ def test_differential_escaped_literals():
     assert vec.planes[5, 2] == vec.planes[6, 2]
 
 
+# The license-statement detector's semantics, pinned as literal answers: a
+# rewrite of ``vocab.LICENSE_STATEMENT_RE`` or of the dedup pass that applies
+# it has to give the same flag on every one of these texts.
+LICENSE_TEXTS = [
+    ("LICENCE: see below", True),
+    ("Copyright ACME Corp", True),
+    ("ALL RIGHTS RESERVED", True),
+    ("(c) 2019 ACME", True),
+    ("(C)2019", True),
+    ("Creative Commons Attribution", True),
+    ("creativecommons", True),
+    ("In the Public Domain", True),
+    ("CC BY 4.0", True),
+    ("cc-by-sa", True),
+    ("licensee", True),
+    ("LİCENSE", True),                  # dotted capital I
+    ("licenſe", True),                  # long s
+    ("Licence ouverte", True),
+    ("x" * 300 + " copyright", True),        # a wide dedup tier
+    ("z" * 9000 + " license " + "z" * 100, True),   # over 8 KiB
+    ("licensing terms", False),
+    ("copy right", False),
+    ("public-domain", False),
+    ("ccby", False),
+    ("(c) ACME", False),
+    ("rights reserved", False),
+    ("CC_BY", False),
+    ("lic ense", False),
+    ("2019", False),
+    ("y" * 300, False),
+]
+
+
+@pytest.mark.parametrize("text,expected", LICENSE_TEXTS,
+                         ids=[f"{i}-{e}" for i, (_, e) in
+                              enumerate(LICENSE_TEXTS)])
+def test_license_statement_flag(text, expected):
+    assert vocab.is_license_statement(text) == expected
+    for suffix in ("", "@fr", f"^^<{vocab.XSD_NS}string>"):
+        line = f'<http://a> <http://b> "{text}"{suffix} .\n'
+        assert_identical(line)
+        d = TermDictionary()
+        tt = parse_encode(line, dictionary=d)
+        flags = int(d.flags[tt.planes[0, 2]])
+        assert bool(flags & vocab.IS_LICENSE_STATEMENT) == expected, suffix
+
+
 # --- malformed-input fuzz corpus (checked in) ---------------------------------
 
 def test_dirty_corpus_differential():

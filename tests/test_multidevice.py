@@ -1,5 +1,5 @@
 """Multi-device behaviours (8 fake CPU devices via subprocess — the main
-test process keeps seeing 1 device, per the dry-run ground rules)."""
+test process keeps seeing 1 device)."""
 import pytest
 
 from conftest import run_subprocess_devices
@@ -192,61 +192,6 @@ for tt, (counts, regs) in zip(tensors, outs):
 print(json.dumps({'ok': bool(ok), 'n': len(outs)}))
 """)
     assert out["ok"] and out["n"] == 5
-
-
-@pytest.mark.slow
-def test_sharded_lm_forward_matches_local():
-    out = run_subprocess_devices(8, """
-import json
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.models.transformer import TransformerConfig, init_transformer, forward
-from repro.dist.sharding import ShardingPolicy
-from repro.launch.mesh import make_host_mesh
-cfg = TransformerConfig(name='t', n_layers=2, d_model=64, n_heads=4,
-    n_kv_heads=2, head_dim=16, d_ff=128, vocab_size=128, moe=True,
-    n_experts=8, n_shared_experts=1, top_k=2, d_ff_expert=32,
-    capacity_factor=4.0, param_dtype=jnp.float32, dtype=jnp.float32,
-    remat='none')
-params, logical = init_transformer(cfg, jax.random.key(0))
-toks = jax.random.randint(jax.random.key(1), (4, 8), 0, 128)
-ref, _ = forward(cfg, params, toks)
-mesh = make_host_mesh(model=4)
-pol = ShardingPolicy(mesh_axes=('data','model'), fsdp=True)
-sp = pol.shardings_for_tree(mesh, logical, params)
-sparams = jax.device_put(params, sp)
-stoks = jax.device_put(toks, NamedSharding(mesh, P('data')))
-out, _ = jax.jit(lambda p, t: forward(cfg, p, t, mesh=mesh, policy=pol))(sparams, stoks)
-err = float(jnp.abs(out - ref).max())
-print(json.dumps({'err': err}))
-""")
-    assert out["err"] < 1e-3
-
-
-@pytest.mark.slow
-def test_compressed_psum_error_feedback():
-    out = run_subprocess_devices(8, """
-import json
-import jax, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.dist import compressed_psum
-from repro.launch.mesh import make_host_mesh
-mesh = make_host_mesh()
-g = jax.jit(jax.shard_map(lambda x, e: compressed_psum(x, 'data', e),
-    mesh=mesh, in_specs=(P('data'), P('data')), out_specs=(P(), P('data'))))
-x = np.random.default_rng(0).normal(size=(64, 32)).astype(np.float32)
-true = x.reshape(8, 8, 32).mean(0)
-r, e = g(x, np.zeros_like(x))
-rel1 = float(np.abs(np.asarray(r) - true).max() / np.abs(true).max())
-acc, t = 0, np.zeros_like(true)
-e = np.zeros_like(x)
-for _ in range(20):
-    r, e = g(x, e); acc = acc + np.asarray(r); t = t + true
-rel20 = float(np.abs(acc - t).max() / np.abs(t).max())
-print(json.dumps({'rel1': rel1, 'rel20': rel20}))
-""")
-    assert out["rel1"] < 0.05
-    assert out["rel20"] < out["rel1"], "error feedback must debias"
 
 
 @pytest.mark.slow
